@@ -82,7 +82,7 @@ def bench_kernels():
         dt, _ = _timeit(lambda: jax.block_until_ready(
             ops.d2_update_tiles(x, c[0], w)))
         rows.append((f"kernel.d2_update_tiles[{n}x{d}]", dt * 1e6,
-                     "tile-sum epilogue for TiledSampleTree.refresh"))
+                     "tile sums for TiledSampleTree.refresh"))
 
     from repro.kernels.flash_attention import flash_attention_pallas
     from repro.kernels.ref import flash_attention_ref
@@ -851,7 +851,7 @@ def bench_heap_update(ns=(1 << 14, 1 << 16, 1 << 18), tile=512, reps=20):
     Times exactly the work a device seeder pays per opened center to keep
     its sample structure consistent AFTER the weight sweep: the old path
     rebuilt a full flat heap (`SampleTreeJax.init`, O(n)); the new path
-    scatters the kernels' tile-sum epilogue into the coarse heap
+    scatters the sweeps' per-tile sums into the coarse heap
     (`TiledSampleTree.refresh`, O(T log T), T = n/tile) — sublinear in n.
     """
     import jax
@@ -979,6 +979,9 @@ def main(argv=None) -> None:
                          "and merges it as serving.net, leaving the "
                          "in-process record untouched")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     all_rows = []
     if args.only == "streaming":
         payload = json.loads(BENCH_JSON.read_text())

@@ -3,9 +3,6 @@
 - Puts `src/` (the package) and the repo root (for `benchmarks.*`) on
   sys.path, so `PYTHONPATH=src` is no longer load-bearing (mirrors the
   `pythonpath` pytest config in pyproject.toml for older runners).
-- If `hypothesis` is not installed (hermetic CI images), registers the
-  deterministic fallback in `tests/_hypothesis_fallback.py` under the
-  `hypothesis` module name so property-based tests still run.
 - If `pytest-timeout` is not installed, registers the watchdog fallback
   in `tests/_pytest_timeout_fallback.py` (same ini/CLI/marker surface),
   so a deadlocked engine test aborts the run in minutes — with all
@@ -22,17 +19,6 @@ ROOT = Path(__file__).resolve().parent
 for p in (str(ROOT / "src"), str(ROOT)):
     if p not in sys.path:
         sys.path.insert(0, p)
-
-try:
-    import hypothesis  # noqa: F401  (the real library wins when present)
-except ImportError:
-    _spec = importlib.util.spec_from_file_location(
-        "hypothesis", ROOT / "tests" / "_hypothesis_fallback.py"
-    )
-    _mod = importlib.util.module_from_spec(_spec)
-    sys.modules["hypothesis"] = _mod
-    _spec.loader.exec_module(_mod)
-    sys.modules["hypothesis.strategies"] = _mod.strategies
 
 try:
     import pytest_timeout  # noqa: F401  (the real plugin wins when present)

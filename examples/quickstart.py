@@ -54,6 +54,9 @@ def main():
     if args.smoke:
         args.n, args.d, args.k = 4000, 8, 25
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from repro.core import (
         BatchSchedule,
         ClusterPlan,

@@ -31,7 +31,8 @@ _PRAGMA = re.compile(r"#\s*repro:\s*disable=([\w,\- ]+)")
 # Call targets that wrap a function into a jit program.
 _JIT_NAMES = {"jax.jit", "jit"}
 _PARTIAL_NAMES = {"functools.partial", "partial"}
-_SHARD_MAP_NAMES = {"shard_map", "jax.experimental.shard_map.shard_map"}
+_SHARD_MAP_NAMES = {"shard_map", "jax.shard_map",
+                    "jax.experimental.shard_map.shard_map"}
 _LRU_NAMES = {"functools.lru_cache", "lru_cache", "functools.cache", "cache"}
 # (call target, positional index of the traced body function[s])
 _LAX_BODY_ARGS = {

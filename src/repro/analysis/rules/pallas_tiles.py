@@ -1,6 +1,6 @@
-"""pallas-tile-shape: kernel tile constants must divide and be annotated.
+"""pallas-tile-shape: kernel tiles must divide, be annotated, and be 2-D.
 
-Two checks, scoped to ``kernels/*.py``:
+Three checks, scoped to ``kernels/*.py``:
 
 1. **divisibility** — a function that issues a ``pl.pallas_call`` whose
    grid floor-divides a dimension by a block parameter must carry a
@@ -14,6 +14,13 @@ Two checks, scoped to ``kernels/*.py``:
    ``BLOCK_SIZE = 128  # TODO: tune`` anti-pattern: defaults chosen on
    one machine ossify silently; the annotation is the breadcrumb the
    real-hardware autotuning track consumes).
+3. **rank-1 blocks** — a ``pl.BlockSpec`` whose block shape is a 1-tuple
+   of a tile (a ``block_*``/``tile*`` name or an int literal) tiles a 1-D
+   array.  Mosaic refuses such a block unless it spans the whole array:
+   XLA lays a 1-D f32 array out in tiles of 1024 and the block's tiling
+   must match, and a ``(1,)`` block matches nothing.  Interpret mode
+   never notices.  Carry the vector lane-dense as a ``(1, n)`` array in
+   ``(1, block)`` blocks instead.
 """
 
 from __future__ import annotations
@@ -107,6 +114,34 @@ def _has_guard(fn: ast.FunctionDef) -> bool:
     return False
 
 
+def _is_tile(node: ast.AST) -> bool:
+    if isinstance(node, ast.Constant):
+        return isinstance(node.value, int) and not isinstance(node.value,
+                                                              bool)
+    return isinstance(node, ast.Name) and bool(
+        _BLOCK_PARAM.match(node.id) or node.id.lower().startswith("tile"))
+
+
+def _check_rank1_blocks(ctx):
+    for node in ast.walk(ctx.tree):
+        if not (isinstance(node, ast.Call) and (dotted_name(node.func) or "")
+                .split(".")[-1] == "BlockSpec"):
+            continue
+        shape = node.args[0] if node.args else next(
+            (kw.value for kw in node.keywords if kw.arg == "block_shape"),
+            None)
+        if isinstance(shape, ast.Tuple) and len(shape.elts) == 1 \
+                and _is_tile(shape.elts[0]):
+            yield Finding(
+                path=ctx.path, line=node.lineno, rule="pallas-tile-shape",
+                message=(f"rank-1 BlockSpec '({ast.unparse(shape.elts[0])},)'"
+                         " tiles a 1-D array: Mosaic refuses rank-1 blocks "
+                         "smaller than the array (XLA tiles 1-D arrays by "
+                         "1024) — use a (1, n) array with (1, block) "
+                         "blocks"),
+            )
+
+
 def _check_divisibility(ctx):
     for fn in ctx.functions:
         has_pallas = any(
@@ -130,9 +165,11 @@ def _check_divisibility(ctx):
 
 @rule("pallas-tile-shape",
       doc="BlockSpec/grid constants must divide padded shapes; tile "
-          "literals need an '# autotune:' annotation")
+          "literals need an '# autotune:' annotation; no rank-1 tile "
+          "blocks")
 def check(ctx, project):
     if not _in_kernels(ctx):
         return
     yield from _check_annotations(ctx)
     yield from _check_divisibility(ctx)
+    yield from _check_rank1_blocks(ctx)

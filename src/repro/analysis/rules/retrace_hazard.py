@@ -29,7 +29,8 @@ from repro.analysis.registry import rule
 from repro.analysis.rules._common import dotted_name, walk_own
 
 _JIT_BUILDERS = {"jax.jit", "jit"}
-_SHARD_MAP = {"shard_map", "jax.experimental.shard_map.shard_map"}
+_SHARD_MAP = {"shard_map", "jax.shard_map",
+              "jax.experimental.shard_map.shard_map"}
 _REBUILD_CTORS = {"SampleTreeJax"}
 _SCALARIZERS = {"int", "float"}
 _SHAPE_ATTRS = {"shape", "ndim", "size"}

@@ -5,12 +5,12 @@ The pointer-machine data structures become arrays (DESIGN.md §3):
     host-side once (O(nd log Δ), embarrassingly vectorisable);
   - MULTITREEOPEN is the fused `tree_sep_update` Pallas kernel per tree
     (compare+reduce+min over all points: O(nH) VPU work, no pointers); the
-    *last* tree's sweep uses the `_tiles` variant, whose free epilogue emits
+    *last* tree's sweep uses the `_tiles` wrapper, which also returns the
     per-tile weight sums;
   - MULTITREESAMPLE is the two-level `TiledSampleTree` descent: a coarse
     flat heap over the T = n/tile tile sums plus one vectorised intra-tile
     cumsum.  After each opened center the coarse heap is fixed *in place*
-    with one `scatter_update` from the kernel epilogue's tile sums —
+    with one `scatter_update` from those tile sums —
     O(T log T) — never rebuilt from scratch (the old per-center
     `SampleTreeJax.init` cost O(n) per open, O(nk) total, and dominated
     large-n seeding);
@@ -122,8 +122,8 @@ def _pad_axis(a: jax.Array, axis: int, n_pad: int) -> jax.Array:
 def _make_open_center(codes_lo, codes_hi, *, scale, num_levels, tile,
                       interpret):
     """Per-center fused sweep over all trees; the last tree's kernel emits
-    the per-tile weight sums the coarse heap update consumes (free epilogue
-    — no extra pass over the points)."""
+    the per-tile weight sums the coarse heap update consumes (one pass over
+    the weight vector, not over the points)."""
     t = codes_lo.shape[0]
 
     def open_center(weights, x):
@@ -171,7 +171,7 @@ def device_fast_kmeanspp(
     (`tracing.TRACE_COUNTS["fastkmeans++/device"]` counts real traces).
 
     Per opened center the sample structure is fixed *incrementally*: the last
-    tree sweep's tile-sum epilogue feeds one `TiledSampleTree.refresh`
+    tree sweep's tile sums feed one `TiledSampleTree.refresh`
     (O(T log T), T = n/tile) — there is no `SampleTreeJax.init` (O(n) heap
     rebuild) anywhere in the loop body.
 
@@ -355,7 +355,7 @@ def device_rejection_sampling(
     reproduces the legacy fixed-batch program (identical RNG stream).
 
     Opening a center never rebuilds the sample structure: the last tree
-    sweep's tile-sum epilogue feeds one incremental
+    sweep's tile sums feed one incremental
     `TiledSampleTree.refresh` (O(T log T), T = n/tile) instead of the old
     O(n) `SampleTreeJax.init` per center.
 

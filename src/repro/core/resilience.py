@@ -215,6 +215,10 @@ _PERMANENT_TYPES = (ValueError, TypeError, KeyError, AssertionError,
 _TRANSIENT_MARKERS = ("RESOURCE_EXHAUSTED", "RESOURCE EXHAUSTED",
                       "OUT OF MEMORY", "OOM", "UNAVAILABLE",
                       "DEADLINE_EXCEEDED", "ABORTED", "INTERNAL:")
+# A program the compiler refuses fails the same way on every attempt, even
+# when XLA wraps the refusal in an INTERNAL status (Mosaic does): it must
+# surface, never be retried or served from a fallback backend.
+_PERMANENT_MARKERS = ("MOSAIC", "COMPILE", "COMPILATION")
 
 
 def classify_failure(exc: BaseException) -> str:
@@ -225,7 +229,8 @@ def classify_failure(exc: BaseException) -> str:
     message carries an allocator/transport status (RESOURCE_EXHAUSTED,
     OOM, UNAVAILABLE, ...), and host-level MemoryError / OSError /
     ConnectionError / TimeoutError.  Permanent failures are request or
-    caller bugs (ValueError, TypeError, quarantine rejections) — retrying
+    caller bugs (ValueError, TypeError, quarantine rejections) and programs
+    the compiler refuses (Mosaic or XLA compile errors) — retrying
     cannot help and MUST NOT feed the circuit breaker, or a single bad
     request could open the circuit for healthy traffic.  Unknown
     exception types default to permanent (no retry storms on logic
@@ -239,6 +244,8 @@ def classify_failure(exc: BaseException) -> str:
     for klass in type(exc).__mro__:
         if klass.__name__ in ("XlaRuntimeError", "JaxRuntimeError"):
             msg = str(exc).upper()
+            if any(m in msg for m in _PERMANENT_MARKERS):
+                return "permanent"
             if any(m in msg for m in _TRANSIENT_MARKERS):
                 return "transient"
             return "permanent"

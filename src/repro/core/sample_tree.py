@@ -16,7 +16,7 @@ on (never a from-scratch `init` inside a seeding loop).
 
 `TiledSampleTree` is the device seeders' two-level variant: leaves are
 *kernel tiles* rather than points — a coarse flat heap holds per-tile weight
-sums (refreshed from the fused kernels' tile-sum epilogue via one
+sums (refreshed from the sweeps' per-tile sums via one
 `scatter_update`, O(T log T) for T = n/tile tiles), and sampling descends the
 coarse heap to a tile then resolves the point with one vectorised intra-tile
 cumsum.  This is also the shard-local sub-heap of the sharded seeding path.
@@ -186,7 +186,7 @@ class TiledSampleTree:
 
     The leaf level is the dense weight array itself (padded to a multiple of
     `tile`); the heap only spans the T = n_pad/tile per-tile sums.  The fused
-    sweep kernels emit those sums as a free epilogue, so the per-center
+    sweeps return those sums with the weights, so the per-center
     sample-structure update is one `scatter_update` on a T-leaf heap —
     O(T log T) with T = n/tile, instead of the O(n) full rebuild the device
     seeders used to pay (`SampleTreeJax.init` per opened center).
@@ -205,7 +205,7 @@ class TiledSampleTree:
         self.coarse = SampleTreeJax(self.num_tiles)
 
     def tile_sums(self, w_pad: jax.Array) -> jax.Array:
-        """(n_pad,) weights -> (T,) per-tile sums (the kernel epilogue's
+        """(n_pad,) weights -> (T,) per-tile sums (the `_tiles` wrappers'
         oracle; used at init time and by tests)."""
         return w_pad.reshape(self.num_tiles, self.tile).sum(axis=1)
 
@@ -214,7 +214,7 @@ class TiledSampleTree:
         return self.coarse.init(self.tile_sums(w_pad))
 
     def refresh(self, heap: jax.Array, tile_sums: jax.Array) -> jax.Array:
-        """Incremental per-center update from the kernels' tile-sum epilogue."""
+        """Incremental per-center update from the sweeps' tile sums."""
         ids = jnp.arange(self.num_tiles, dtype=jnp.int32)
         return self.coarse.scatter_update(heap, ids, tile_sums)
 

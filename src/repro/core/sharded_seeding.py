@@ -6,7 +6,7 @@ Layout (docs/sample_tree.md): every per-point tensor — multi-tree codes
 (T, H, n), coordinates (n, d), LSH bucket keys (L, n), and the D^2 weight
 vector — is split into D contiguous leaf ranges, one per device.  Each shard
 owns a *local sub-heap* (`TiledSampleTree` over its own tiles, refreshed
-incrementally from the fused kernels' tile-sum epilogue) and the only
+incrementally from the sweeps' per-tile sums) and the only
 replicated sampling state is the tiny top-tree: the (D,) vector of shard
 totals, produced by one `all_gather` per draw.
 
@@ -49,7 +49,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.batch_schedule import BatchSchedule
@@ -147,7 +146,8 @@ def _broadcast_from_owner(x_glob, n_loc, axis, *columns):
 def _make_local_open(codes_lo_loc, codes_hi_loc, *, scale, num_levels, tile,
                      interpret):
     """Sharded MULTITREEOPEN: each device sweeps only its own points; the
-    last tree's kernel emits the local tile sums for the sub-heap refresh."""
+    last tree's sweep also returns the local tile sums for the sub-heap
+    refresh."""
     t = codes_lo_loc.shape[0]
 
     def open_center(weights, col_lo, col_hi):
@@ -222,11 +222,11 @@ def _fastkmeanspp_program(mesh, t, h, n_pad, k, scale, num_levels, m_init,
         )
         return chosen
 
-    fn = shard_map(
+    fn = jax.shard_map(
         program, mesh=mesh,
         in_specs=(P(None, None, axis), P(None, None, axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -384,14 +384,14 @@ def _rejection_program(mesh, t, h, n_pad, l, d, k, scale, num_levels, m_init,
         out = jax.lax.fori_loop(0, k, body, state0)
         return out[2], out[6]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         program, mesh=mesh,
         in_specs=(
             P(None, None, axis), P(None, None, axis),
             P(axis, None), P(None, axis), P(None, axis), P(),
         ),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -485,11 +485,11 @@ def _kmeans_parallel_program(mesh, n_pad, d, rounds, cap_loc, n_real,
         _, sel, _ = jax.lax.fori_loop(0, rounds, round_body, (key, sel, d2))
         return sel
 
-    fn = shard_map(
+    fn = jax.shard_map(
         program, mesh=mesh,
         in_specs=(P(axis, None), P(), P()),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 

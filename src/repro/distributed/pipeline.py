@@ -19,7 +19,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 __all__ = ["pipeline_apply"]
 
@@ -81,8 +80,8 @@ def pipeline_apply(
         P(),              # microbatches replicated into every stage
     )
     out_specs = P()
-    fn_sm = shard_map(
+    fn_sm = jax.shard_map(
         per_stage, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
     return fn_sm(stage_params, x)

@@ -3,9 +3,9 @@
 - `pairwise_argmin`    — nearest-center search (Lloyd / k-means++ / acceptance)
 - `d2_update`          — fused D^2 weight maintenance for one new center
 - `tree_sep_update`    — MULTITREEOPEN's per-tree weight sweep
-- `*_tiles` variants   — same sweeps with a free per-tile weight-sum
-                         epilogue feeding the coarse `TiledSampleTree` heap
-                         (the incremental per-center sample-structure update)
+- `*_tiles` variants   — same sweeps plus the per-tile weight sums that feed
+                         the coarse `TiledSampleTree` heap (the incremental
+                         per-center sample-structure update)
 - `lsh_bucket_min`     — monotone-LSH nearest-bucket query (Algorithm 4's
                          acceptance test: nearest colliding opened center)
 - `lsh_bucket_accept`  — same query + fused acceptance-probability epilogue

@@ -6,11 +6,11 @@ rejection seeder's bookkeeping.  Fusing the distance computation with the
 min-update halves HBM traffic vs materialising the distance vector
 (read x + w, write w; no intermediate).
 
-The `_tiles` variant adds a free epilogue: each grid step also emits the
-tile's *new weight sum* (one (1,) lane per tile), which is exactly the leaf
-update the coarse `TiledSampleTree` heap needs — so the sample structure can
-be fixed incrementally (O(T log T) scatter) instead of rebuilt O(n) after
-every opened center.
+The weight vector travels lane-dense as a ``(1, n)`` array in ``(1,
+block_n)`` blocks: Mosaic refuses rank-1 blocks smaller than the array
+(XLA tiles a 1-D f32 array by 1024, the block by `block_n`).  Per-tile
+weight sums for the `TiledSampleTree` heap are reduced outside the kernel
+(`ops.d2_update_tiles`).
 
 Grid: 1-D over point tiles; the center row is broadcast to every tile
 (a (1, d) block with a constant index map).
@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["d2_update_pallas", "d2_update_tiles_pallas"]
+__all__ = ["d2_update_pallas"]
 
 
 def _kernel(x_ref, c_ref, w_ref, out_ref):
@@ -32,70 +32,32 @@ def _kernel(x_ref, c_ref, w_ref, out_ref):
     c = c_ref[...].astype(jnp.float32)       # (1, D)
     diff = x - c
     d2 = jnp.sum(diff * diff, axis=1)        # (BN,)
-    out_ref[...] = jnp.minimum(w_ref[...].astype(jnp.float32), d2)
-
-
-def _kernel_tiles(x_ref, c_ref, w_ref, out_ref, tsum_ref):
-    _kernel(x_ref, c_ref, w_ref, out_ref)
-    tsum_ref[...] = jnp.sum(out_ref[...], keepdims=True)
+    out_ref[...] = jnp.minimum(w_ref[...].astype(jnp.float32),
+                               d2.reshape(1, -1))
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def d2_update_pallas(
     x: jax.Array,
     center: jax.Array,
-    w: jax.Array,
+    w: jax.Array,            # (1, n) f32
     *,
     block_n: int = 512,  # autotune: VMEM-sized row tile; retune on hw
     interpret: bool = False,
 ):
-    """Pre-padded inputs (n % block_n == 0); see `ops.d2_update`."""
+    """Pre-padded inputs (n % block_n == 0); returns (1, n).  See
+    `ops.d2_update`."""
     n, d = x.shape
-    assert n % block_n == 0
+    assert n % block_n == 0, (n, block_n)
     return pl.pallas_call(
         _kernel,
         grid=(n // block_n,),
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i: (i, 0)),
             pl.BlockSpec((1, d), lambda i: (0, 0)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((1, block_n), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
-        interpret=interpret,
-    )(x, center.reshape(1, -1), w)
-
-
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def d2_update_tiles_pallas(
-    x: jax.Array,
-    center: jax.Array,
-    w: jax.Array,
-    *,
-    block_n: int = 512,  # autotune: VMEM-sized row tile; retune on hw
-    interpret: bool = False,
-):
-    """As `d2_update_pallas`, plus the per-tile new-sum epilogue.
-
-    Returns ``(w' (n,), tile_sums (n // block_n,))``; pre-padded inputs.
-    """
-    n, d = x.shape
-    assert n % block_n == 0
-    return pl.pallas_call(
-        _kernel_tiles,
-        grid=(n // block_n,),
-        in_specs=[
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, d), lambda i: (0, 0)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((n // block_n,), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
     )(x, center.reshape(1, -1), w)

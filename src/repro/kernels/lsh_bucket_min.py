@@ -19,7 +19,10 @@ Grid: ``(B // BB, K // BK)`` with the center dimension minor so the output
 tile stays resident in VMEM while center tiles sweep (the `pairwise_argmin`
 accumulation pattern).  A miss leaves the lane at ``MISS`` (3e38, finite so
 downstream f32 arithmetic stays NaN-free); callers compare against
-``MISS / 2`` to detect it.
+``MISS / 2`` to detect it.  The per-candidate vectors (``mtd2``, the
+outputs) are lane-dense ``(1, B)`` arrays in ``(1, block_b)`` blocks: Mosaic
+refuses rank-1 blocks smaller than the array.  The matmul runs at
+`MATMUL_PRECISION`, for the reason `pairwise_argmin` gives.
 
 The `_accept` variant fuses the rejection sampler's acceptance epilogue: at
 the final center tile (the accumulated min is then complete) it also emits
@@ -37,6 +40,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.pairwise_argmin import MATMUL_PRECISION
 
 __all__ = ["lsh_bucket_min_pallas", "lsh_bucket_accept_pallas", "LSH_MISS"]
 
@@ -67,7 +72,8 @@ def _kernel(qk_lo_ref, qk_hi_ref, q_ref, ck_lo_ref, ck_hi_ref, c_ref,
     q = q_ref[...].astype(jnp.float32)     # (BB, D)
     c = c_ref[...].astype(jnp.float32)     # (BK, D)
     dots = jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, c, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
+        preferred_element_type=jnp.float32,
     )                                      # (BB, BK) on the MXU
     q_sq = jnp.sum(q * q, axis=1, keepdims=True)       # (BB, 1)
     c_sq = jnp.sum(c * c, axis=1, keepdims=True).T     # (1, BK)
@@ -76,7 +82,8 @@ def _kernel(qk_lo_ref, qk_hi_ref, q_ref, ck_lo_ref, ck_hi_ref, c_ref,
     # penalty row: 0 for live centers, LSH_MISS for padded / not-yet-opened
     # slots — the max() turns any accidental collision with them into a miss.
     masked = jnp.maximum(jnp.where(collide, d2, LSH_MISS), pen_ref[...])
-    out_ref[...] = jnp.minimum(out_ref[...], jnp.min(masked, axis=1))
+    out_ref[...] = jnp.minimum(out_ref[...],
+                               jnp.min(masked, axis=1).reshape(1, -1))
 
 
 def _kernel_accept(qk_lo_ref, qk_hi_ref, q_ref, ck_lo_ref, ck_hi_ref, c_ref,
@@ -110,7 +117,7 @@ def lsh_bucket_min_pallas(
     interpret: bool = False,
 ):
     """Pre-padded inputs (B % block_b == 0, K % block_k == 0, L % 8 == 0);
-    see `ops.lsh_bucket_min` for the padding/unpadding wrapper."""
+    returns (1, B).  See `ops.lsh_bucket_min` for the padding wrapper."""
     l, b = q_keys_lo.shape
     k = c_keys_lo.shape[1]
     assert b % block_b == 0 and k % block_k == 0, (b, k, block_b, block_k)
@@ -128,8 +135,8 @@ def lsh_bucket_min_pallas(
             pl.BlockSpec((block_k, d), lambda i, j: (j, 0)),
             pl.BlockSpec((1, block_k), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((block_b,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((b,), jnp.float32),
+        out_specs=pl.BlockSpec((1, block_b), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, b), jnp.float32),
         interpret=interpret,
     )(q_keys_lo, q_keys_hi, q, c_keys_lo, c_keys_hi, c, penalty)
 
@@ -145,7 +152,7 @@ def lsh_bucket_accept_pallas(
     c_keys_hi: jax.Array,
     c: jax.Array,            # (K, D) f32
     penalty: jax.Array,      # (1, K) f32
-    mtd2: jax.Array,         # (B,) f32 — current multi-tree D^2 weights
+    mtd2: jax.Array,         # (1, B) f32 — current multi-tree D^2 weights
     *,
     c2: float,
     block_b: int = 128,  # autotune: lane-width tile; retune on hw
@@ -154,7 +161,7 @@ def lsh_bucket_accept_pallas(
 ):
     """`lsh_bucket_min_pallas` + the fused acceptance-probability epilogue.
 
-    Returns ``(d2_min (B,), p_accept (B,))``; pre-padded inputs as in
+    Returns ``(d2_min (1, B), p_accept (1, B))``; pre-padded inputs as in
     `lsh_bucket_min_pallas`, ``mtd2`` padded to the candidate block multiple.
     """
     l, b = q_keys_lo.shape
@@ -173,15 +180,15 @@ def lsh_bucket_accept_pallas(
             pl.BlockSpec((l, block_k), lambda i, j: (0, j)),
             pl.BlockSpec((block_k, d), lambda i, j: (j, 0)),
             pl.BlockSpec((1, block_k), lambda i, j: (0, j)),
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
+            pl.BlockSpec((1, block_b), lambda i, j: (0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
+            pl.BlockSpec((1, block_b), lambda i, j: (0, i)),
+            pl.BlockSpec((1, block_b), lambda i, j: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b,), jnp.float32),
-            jax.ShapeDtypeStruct((b,), jnp.float32),
+            jax.ShapeDtypeStruct((1, b), jnp.float32),
+            jax.ShapeDtypeStruct((1, b), jnp.float32),
         ],
         interpret=interpret,
     )(q_keys_lo, q_keys_hi, q, c_keys_lo, c_keys_hi, c, penalty, mtd2)

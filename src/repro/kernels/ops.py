@@ -1,9 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-Handle padding/unpadding to kernel block multiples and choose the execution
-mode: compiled Pallas on TPU, `interpret=True` elsewhere (the kernel body
-then runs as reference Python/XLA ops on CPU — bit-identical semantics, used
-by tests).  Every wrapper has a pure-jnp oracle in `ref.py`.
+Handle padding/unpadding to kernel block multiples, the kernels' lane-dense
+``(1, n)`` per-point layout, and the execution mode: compiled Pallas on TPU,
+`interpret=True` elsewhere (the kernel body then runs as reference
+Python/XLA ops on CPU — bit-identical semantics, used by tests).  Every
+wrapper has a pure-jnp oracle in `ref.py`.
 """
 
 from __future__ import annotations
@@ -15,17 +16,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref
-from repro.kernels.d2_update import d2_update_pallas, d2_update_tiles_pallas
+from repro.kernels.d2_update import d2_update_pallas
 from repro.kernels.lsh_bucket_min import (
     LSH_MISS,
     lsh_bucket_accept_pallas,
     lsh_bucket_min_pallas,
 )
 from repro.kernels.pairwise_argmin import pairwise_argmin_pallas
-from repro.kernels.tree_sep_update import (
-    tree_sep_update_pallas,
-    tree_sep_update_tiles_pallas,
-)
+from repro.kernels.tree_sep_update import tree_sep_update_pallas
 
 __all__ = [
     "pairwise_argmin",
@@ -57,6 +55,17 @@ def _pad_to(a: jax.Array, axis: int, multiple: int, value) -> jax.Array:
     return jnp.pad(a, widths, constant_values=value)
 
 
+def _row(v: jax.Array) -> jax.Array:
+    """(n,) -> the kernels' lane-dense (1, n) layout."""
+    return v.reshape(1, -1)
+
+
+def _tile_sums(w_pad: jax.Array, block_n: int) -> jax.Array:
+    """Per-tile sums of a padded weight vector (the `TiledSampleTree`
+    leaf update), reduced by XLA next to the kernel."""
+    return w_pad.reshape(-1, block_n).sum(axis=1)
+
+
 def pairwise_argmin(
     x: jax.Array,
     c: jax.Array,
@@ -82,7 +91,18 @@ def pairwise_argmin(
     d2, idx = pairwise_argmin_pallas(
         xp, cp, block_n=block_n, block_k=block_k, interpret=interpret
     )
-    return d2[:n], idx[:n]
+    return d2[0, :n], idx[0, :n]
+
+
+def _d2_update_padded(x, center, w, block_n, interpret):
+    """The d2 sweep on block-padded inputs; returns the padded (n_pad,) w'
+    (padding lanes carry w=0, so they add nothing to tile sums)."""
+    if interpret is None:
+        interpret = default_interpret()
+    xp = _pad_to(x, 0, block_n, 0)
+    wp = _pad_to(w, 0, block_n, 0.0)
+    return d2_update_pallas(xp, center, _row(wp), block_n=block_n,
+                            interpret=interpret)[0]
 
 
 def d2_update(
@@ -94,13 +114,7 @@ def d2_update(
     interpret: bool | None = None,
 ) -> jax.Array:
     """w <- min(w, ||x - center||^2); any n, pads internally."""
-    if interpret is None:
-        interpret = default_interpret()
-    n = x.shape[0]
-    xp = _pad_to(x, 0, block_n, 0)
-    wp = _pad_to(w, 0, block_n, 0.0)
-    out = d2_update_pallas(xp, center, wp, block_n=block_n, interpret=interpret)
-    return out[:n]
+    return _d2_update_padded(x, center, w, block_n, interpret)[:x.shape[0]]
 
 
 def d2_update_tiles(
@@ -111,17 +125,33 @@ def d2_update_tiles(
     block_n: int = 512,  # autotune: VMEM-sized row tile; retune on hw
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """(w', per-tile sums); any n, pads internally (padding lanes carry w=0
-    so they contribute nothing to the tile sums).  Returns the *padded*
+    """(w', per-tile sums); any n, pads internally.  Returns the *padded*
     weight vector alongside the (ceil(n/block_n),) sums — callers running
     the incremental `TiledSampleTree` path keep the padded layout as loop
     state, so no per-call unpad slicing."""
+    out = _d2_update_padded(x, center, w, block_n, interpret)
+    return out, _tile_sums(out, block_n)
+
+
+def _tree_sep_padded(codes_lo, codes_hi, center_lo, center_hi, w, *, scale,
+                     num_levels, block_n, interpret):
+    """The tree sweep on block-padded inputs; returns the padded w'.
+
+    Height padding (to a sublane multiple of 8) uses codes that can never
+    match (-1 vs -2), so padded heights contribute nothing to `sep`.
+    """
     if interpret is None:
         interpret = default_interpret()
-    xp = _pad_to(x, 0, block_n, 0)
+    lo = _pad_to(_pad_to(codes_lo, 1, block_n, 0), 0, 8, -1)
+    hi = _pad_to(_pad_to(codes_hi, 1, block_n, 0), 0, 8, -1)
+    clo = _pad_to(center_lo, 0, 8, -2)
+    chi = _pad_to(center_hi, 0, 8, -2)
     wp = _pad_to(w, 0, block_n, 0.0)
-    return d2_update_tiles_pallas(xp, center, wp, block_n=block_n,
-                                  interpret=interpret)
+    return tree_sep_update_pallas(
+        lo, hi, clo, chi, _row(wp),
+        scale=scale, num_levels=num_levels, block_n=block_n,
+        interpret=interpret,
+    )[0]
 
 
 def tree_sep_update(
@@ -136,25 +166,11 @@ def tree_sep_update(
     block_n: int = 1024,  # autotune: VMEM-sized row tile; retune on hw
     interpret: bool | None = None,
 ) -> jax.Array:
-    """One tree's open-center weight sweep; any n, pads internally.
-
-    Height padding (to a sublane multiple of 8) uses codes that can never
-    match (-1 vs -2), so padded heights contribute nothing to `sep`.
-    """
-    if interpret is None:
-        interpret = default_interpret()
-    h, n = codes_lo.shape
-    lo = _pad_to(_pad_to(codes_lo, 1, block_n, 0), 0, 8, -1)
-    hi = _pad_to(_pad_to(codes_hi, 1, block_n, 0), 0, 8, -1)
-    clo = _pad_to(center_lo, 0, 8, -2)
-    chi = _pad_to(center_hi, 0, 8, -2)
-    wp = _pad_to(w, 0, block_n, 0.0)
-    out = tree_sep_update_pallas(
-        lo, hi, clo, chi, wp,
-        scale=scale, num_levels=num_levels, block_n=block_n,
-        interpret=interpret,
-    )
-    return out[:n]
+    """One tree's open-center weight sweep; any n, pads internally."""
+    out = _tree_sep_padded(codes_lo, codes_hi, center_lo, center_hi, w,
+                           scale=scale, num_levels=num_levels,
+                           block_n=block_n, interpret=interpret)
+    return out[:codes_lo.shape[1]]
 
 
 def tree_sep_update_tiles(
@@ -175,18 +191,10 @@ def tree_sep_update_tiles(
     device seeders carry the padded weight vector across centers and feed
     the sums straight into `TiledSampleTree.refresh`.
     """
-    if interpret is None:
-        interpret = default_interpret()
-    lo = _pad_to(_pad_to(codes_lo, 1, block_n, 0), 0, 8, -1)
-    hi = _pad_to(_pad_to(codes_hi, 1, block_n, 0), 0, 8, -1)
-    clo = _pad_to(center_lo, 0, 8, -2)
-    chi = _pad_to(center_hi, 0, 8, -2)
-    wp = _pad_to(w, 0, block_n, 0.0)
-    return tree_sep_update_tiles_pallas(
-        lo, hi, clo, chi, wp,
-        scale=scale, num_levels=num_levels, block_n=block_n,
-        interpret=interpret,
-    )
+    out = _tree_sep_padded(codes_lo, codes_hi, center_lo, center_hi, w,
+                           scale=scale, num_levels=num_levels,
+                           block_n=block_n, interpret=interpret)
+    return out, _tile_sums(out, block_n)
 
 
 def lsh_bucket_min(
@@ -228,7 +236,7 @@ def lsh_bucket_min(
         qlo, qhi, qp, clo, chi, cp, penalty,
         block_b=block_b, block_k=block_k, interpret=interpret,
     )
-    return out[:b]
+    return out[0, :b]
 
 
 def lsh_bucket_accept(
@@ -266,10 +274,10 @@ def lsh_bucket_accept(
     live = jnp.arange(cp.shape[0]) < (k if count is None else count)
     penalty = jnp.where(live, 0.0, LSH_MISS).astype(jnp.float32)[None, :]
     d2_min, p = lsh_bucket_accept_pallas(
-        qlo, qhi, qp, clo, chi, cp, penalty, mp,
+        qlo, qhi, qp, clo, chi, cp, penalty, _row(mp),
         c2=c2, block_b=block_b, block_k=block_k, interpret=interpret,
     )
-    return d2_min[:b], p[:b]
+    return d2_min[0, :b], p[0, :b]
 
 
 def split_codes_u64(codes: np.ndarray) -> tuple[np.ndarray, np.ndarray]:

@@ -12,7 +12,16 @@ kernel sweeps center tiles (the standard Pallas accumulation pattern).
 
 Block shapes default to (128, d) x (128, d): MXU-aligned on the matmul
 dims; d stays un-tiled because clustering dimensionality (<= a few hundred)
-fits VMEM comfortably: 2 * 128 * d * 4B ~ 0.1-0.4 MB << 16 MB.
+fits VMEM comfortably: 2 * 128 * d * 4B ~ 0.1-0.4 MB << 16 MB.  The
+per-point outputs are lane-dense ``(1, n)`` arrays in ``(1, block_n)``
+blocks (Mosaic refuses rank-1 blocks smaller than the array).
+
+The matmul runs at `MATMUL_PRECISION`, f32 on the MXU.  The expansion
+cancels for nearby points, so a bf16 pass would misorder argmins at real
+coordinate scales.  On a v5e, Mosaic's default for f32 operands measured
+the same (max error 2.9e-7 of |x|^2 + |c|^2 against float64 at KDD-Cup
+width, `chip_smoke.py`); HIGHEST states the requirement instead of relying
+on that default.
 """
 
 from __future__ import annotations
@@ -23,7 +32,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["pairwise_argmin_pallas"]
+__all__ = ["pairwise_argmin_pallas", "MATMUL_PRECISION"]
+
+MATMUL_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def _kernel(x_ref, c_ref, min_ref, arg_ref, *, block_k: int):
@@ -37,14 +48,16 @@ def _kernel(x_ref, c_ref, min_ref, arg_ref, *, block_k: int):
     x = x_ref[...].astype(jnp.float32)           # (BN, D)
     c = c_ref[...].astype(jnp.float32)           # (BK, D)
     dots = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, c, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
+        preferred_element_type=jnp.float32,
     )                                            # (BN, BK) on the MXU
     x_sq = jnp.sum(x * x, axis=1, keepdims=True)          # (BN, 1)
     c_sq = jnp.sum(c * c, axis=1, keepdims=True).T        # (1, BK)
     d2 = jnp.maximum(x_sq - 2.0 * dots + c_sq, 0.0)
 
-    local_min = jnp.min(d2, axis=1)
-    local_arg = jnp.argmin(d2, axis=1).astype(jnp.int32) + j * block_k
+    local_min = jnp.min(d2, axis=1).reshape(1, -1)               # (1, BN)
+    local_arg = (jnp.argmin(d2, axis=1).astype(jnp.int32)
+                 + j * block_k).reshape(1, -1)
 
     better = local_min < min_ref[...]
     min_ref[...] = jnp.where(better, local_min, min_ref[...])
@@ -60,7 +73,7 @@ def pairwise_argmin_pallas(
     block_k: int = 128,  # autotune: lane-width tile; retune on hw
     interpret: bool = False,
 ):
-    """(min_d2 f32 (n,), argmin int32 (n,)).  Requires pre-padded inputs:
+    """(min_d2 f32 (1, n), argmin int32 (1, n)).  Requires pre-padded inputs:
     n % block_n == 0, k % block_k == 0 (use `ops.pairwise_argmin` for the
     padding/unpadding wrapper)."""
     n, d = x.shape
@@ -75,12 +88,12 @@ def pairwise_argmin_pallas(
             pl.BlockSpec((block_k, d), lambda i, j: (j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
+            pl.BlockSpec((1, block_n), lambda i, j: (0, i)),
+            pl.BlockSpec((1, block_n), lambda i, j: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((1, n), jnp.float32),
+            jax.ShapeDtypeStruct((1, n), jnp.int32),
         ],
         interpret=interpret,
     )(x, c)

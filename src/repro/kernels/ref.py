@@ -21,7 +21,7 @@ __all__ = [
 
 
 def _tile_sums_ref(w: jax.Array, block_n: int) -> jax.Array:
-    """Per-tile weight sums — the `_tiles` kernels' epilogue oracle."""
+    """Per-tile weight sums — the `_tiles` wrappers' oracle."""
     return w.reshape(-1, block_n).sum(axis=1)
 
 
@@ -52,7 +52,7 @@ def d2_update_ref(x: jax.Array, center: jax.Array, w: jax.Array):
 
 def d2_update_tiles_ref(x: jax.Array, center: jax.Array, w: jax.Array, *,
                         block_n: int = 512):  # autotune: matches pallas default
-    """(w', per-tile sums of w') — `d2_update_tiles_pallas` oracle."""
+    """(w', per-tile sums of w') — `ops.d2_update_tiles` oracle."""
     out = d2_update_ref(x, center, w)
     return out, _tile_sums_ref(out, block_n)
 
@@ -91,7 +91,7 @@ def tree_sep_update_tiles_ref(
     num_levels: int,
     block_n: int = 512,  # autotune: matches pallas default
 ):
-    """(w', per-tile sums of w') — `tree_sep_update_tiles_pallas` oracle."""
+    """(w', per-tile sums of w') — `ops.tree_sep_update_tiles` oracle."""
     out = tree_sep_update_ref(codes_lo, codes_hi, center_lo, center_hi, w,
                               scale=scale, num_levels=num_levels)
     return out, _tile_sums_ref(out, block_n)

@@ -13,13 +13,13 @@ The kernel fuses, per point tile:
 Cell codes are 64-bit hashes stored as two int32 planes (TPU has no 64-bit
 integers); equality requires both planes to agree.  The (H, BN) code tiles
 put points in the lane dimension; H (~20-32, padded to a multiple of 8) sits
-in sublanes.
+in sublanes, and the weight vector is a lane-dense ``(1, n)`` array in
+``(1, block_n)`` blocks (Mosaic refuses rank-1 blocks smaller than the
+array).
 
 Grid: 1-D over point tiles; the opened center's code column is broadcast.
-The `_tiles` variant adds the tile-sum epilogue (each grid step also emits
-the tile's new weight sum) feeding the coarse `TiledSampleTree` heap's
-incremental scatter update — the device seeders' replacement for the old
-per-center O(n) heap rebuild.
+The per-tile weight sums that the coarse `TiledSampleTree` heap consumes
+are reduced outside the kernel (`ops.tree_sep_update_tiles`).
 """
 
 from __future__ import annotations
@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["tree_sep_update_pallas", "tree_sep_update_tiles_pallas"]
+__all__ = ["tree_sep_update_pallas"]
 
 
 def _kernel(lo_ref, hi_ref, clo_ref, chi_ref, w_ref, out_ref, *,
@@ -40,19 +40,12 @@ def _kernel(lo_ref, hi_ref, clo_ref, chi_ref, w_ref, out_ref, *,
     clo = clo_ref[...]                     # (H, 1) int32
     chi = chi_ref[...]
     eq = (lo == clo) & (hi == chi)         # (H, BN)
-    sep = 1 + jnp.sum(eq.astype(jnp.int32), axis=0)        # (BN,)
+    sep = 1 + jnp.sum(eq.astype(jnp.int32), axis=0, keepdims=True)  # (1, BN)
     dist = scale * (
         jnp.exp2(1.0 - sep.astype(jnp.float32)) - 2.0 ** (1.0 - num_levels)
     )
     dist = jnp.maximum(dist, 0.0)
     out_ref[...] = jnp.minimum(w_ref[...].astype(jnp.float32), dist * dist)
-
-
-def _kernel_tiles(lo_ref, hi_ref, clo_ref, chi_ref, w_ref, out_ref, tsum_ref,
-                  *, scale: float, num_levels: int):
-    _kernel(lo_ref, hi_ref, clo_ref, chi_ref, w_ref, out_ref,
-            scale=scale, num_levels=num_levels)
-    tsum_ref[...] = jnp.sum(out_ref[...], keepdims=True)
 
 
 @functools.partial(
@@ -63,16 +56,17 @@ def tree_sep_update_pallas(
     codes_hi: jax.Array,    # (H, n) int32
     center_lo: jax.Array,   # (H,) int32
     center_hi: jax.Array,   # (H,) int32
-    w: jax.Array,           # (n,) f32
+    w: jax.Array,           # (1, n) f32
     *,
     scale: float,
     num_levels: int,
     block_n: int = 1024,  # autotune: VMEM-sized row tile; retune on hw
     interpret: bool = False,
 ):
-    """Pre-padded inputs (n % block_n == 0); see `ops.tree_sep_update`."""
+    """Pre-padded inputs (n % block_n == 0); returns (1, n).  See
+    `ops.tree_sep_update`."""
     h, n = codes_lo.shape
-    assert n % block_n == 0
+    assert n % block_n == 0, (n, block_n)
     return pl.pallas_call(
         functools.partial(_kernel, scale=scale, num_levels=num_levels),
         grid=(n // block_n,),
@@ -81,52 +75,9 @@ def tree_sep_update_pallas(
             pl.BlockSpec((h, block_n), lambda i: (0, i)),
             pl.BlockSpec((h, 1), lambda i: (0, 0)),
             pl.BlockSpec((h, 1), lambda i: (0, 0)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((1, block_n), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
-        interpret=interpret,
-    )(codes_lo, codes_hi, center_lo.reshape(-1, 1), center_hi.reshape(-1, 1), w)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("block_n", "scale", "num_levels", "interpret")
-)
-def tree_sep_update_tiles_pallas(
-    codes_lo: jax.Array,    # (H, n) int32
-    codes_hi: jax.Array,    # (H, n) int32
-    center_lo: jax.Array,   # (H,) int32
-    center_hi: jax.Array,   # (H,) int32
-    w: jax.Array,           # (n,) f32
-    *,
-    scale: float,
-    num_levels: int,
-    block_n: int = 512,  # autotune: VMEM-sized row tile; retune on hw
-    interpret: bool = False,
-):
-    """As `tree_sep_update_pallas`, plus the per-tile new-sum epilogue.
-
-    Returns ``(w' (n,), tile_sums (n // block_n,))``; pre-padded inputs.
-    """
-    h, n = codes_lo.shape
-    assert n % block_n == 0
-    return pl.pallas_call(
-        functools.partial(_kernel_tiles, scale=scale, num_levels=num_levels),
-        grid=(n // block_n,),
-        in_specs=[
-            pl.BlockSpec((h, block_n), lambda i: (0, i)),
-            pl.BlockSpec((h, block_n), lambda i: (0, i)),
-            pl.BlockSpec((h, 1), lambda i: (0, 0)),
-            pl.BlockSpec((h, 1), lambda i: (0, 0)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((n // block_n,), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
     )(codes_lo, codes_hi, center_lo.reshape(-1, 1), center_hi.reshape(-1, 1), w)

@@ -25,6 +25,7 @@ import time
 import numpy as np
 
 from repro.core import ClusterSpec, ExecutionSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.net import (
     ClusterClient,
     ClusterServer,
@@ -45,6 +46,40 @@ def _build_server(args) -> ClusterServer:
         max_pending=args.max_pending, backpressure=args.backpressure)
 
 
+def drive(srv: ClusterServer, requests, *, timeout: float = 300.0):
+    """Send ``(points, seed, tenant)`` requests to a running server.
+
+    Submits every request through a real `ClusterClient` over loopback
+    sockets, waits for each terminal frame, and returns ``(outcomes,
+    stats)``: one `FitResult` or exception per request, in order, and the
+    server's stats once its delivery counters cover this burst.
+    """
+    with ClusterClient(*srv.address) as client:
+        net0 = client.stats(timeout=60.0)["net"]
+        done0 = net0["results_sent"] + net0["errors_sent"]
+        ids = [client.submit(points, seed=seed, tenant=tenant)
+               for points, seed, tenant in requests]
+        outcomes = {}
+        for rid in client.as_completed(ids, timeout=timeout):
+            try:
+                outcomes[rid] = client.result(rid, timeout=60.0)
+            except Exception as e:  # noqa: BLE001 — returned to the caller
+                outcomes[rid] = e
+        # The server bumps its delivery counters AFTER the terminal
+        # frame hits the socket, so a stats probe racing the last
+        # delivery can read one short — poll until the ledger covers
+        # the burst (bounded; a genuine shortfall shows in the counts).
+        settle = time.monotonic() + 10.0
+        while True:
+            stats = client.stats(timeout=60.0)
+            net = stats["net"]
+            if (net["results_sent"] + net["errors_sent"]
+                    >= done0 + len(ids) or time.monotonic() > settle):
+                break
+            time.sleep(0.05)
+    return [outcomes[rid] for rid in ids], stats
+
+
 def _smoke(args) -> int:
     """Loopback exercise: burst N fits via sockets, print the breakdown."""
     rng = np.random.default_rng(0)
@@ -59,31 +94,14 @@ def _smoke(args) -> int:
         print(f"smoke: serving on {srv.address[0]}:{srv.address[1]} "
               f"(backend={args.backend}, max_batch={args.max_batch}, "
               f"max_wait_ms={args.max_wait_ms:g})")
-        with ClusterClient(*srv.address) as client:
-            ids = [client.submit(ds, seed=i,
-                                 tenant=tenants[i % len(tenants)])
-                   for i, ds in enumerate(datasets)]
-            failed = 0
-            for rid in client.as_completed(ids, timeout=300.0):
-                try:
-                    client.result(rid, timeout=60.0)
-                except Exception as e:  # noqa: BLE001 — counted, reported
-                    failed += 1
-                    print(f"smoke: request {rid} FAILED: {e!r}")
-            # The server bumps its delivery counters AFTER the terminal
-            # frame hits the socket, so a stats probe racing the last
-            # delivery can read one short — poll until the ledger
-            # covers the burst (bounded; a genuine shortfall still
-            # fails below).
-            settle = time.monotonic() + 10.0
-            while True:
-                stats = client.stats(timeout=60.0)
-                net = stats["net"]
-                if (net["results_sent"] + net["errors_sent"]
-                        >= len(datasets)
-                        or time.monotonic() > settle):
-                    break
-                time.sleep(0.05)
+        outcomes, stats = drive(
+            srv, [(ds, i, tenants[i % len(tenants)])
+                  for i, ds in enumerate(datasets)])
+    failed = 0
+    for i, out in enumerate(outcomes):
+        if isinstance(out, BaseException):
+            failed += 1
+            print(f"smoke: request {i} FAILED: {out!r}")
     net = stats["net"]
     bd = net["breakdown"]
     attributed = bd["queue_wait_s"] + bd["solve_s"] + bd["network_s"]
@@ -140,6 +158,7 @@ def main(argv=None) -> int:
                     help="dimensions per smoke dataset")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     if args.smoke:
         return _smoke(args)
     with _build_server(args) as srv:

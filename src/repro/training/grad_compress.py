@@ -24,7 +24,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 __all__ = [
     "int8_compress",
@@ -103,10 +102,10 @@ def make_ddp_step(loss_fn, mesh: Mesh, axis_name: str = "data",
         )
 
     batch_spec = P(axis_name)
-    return shard_map(
+    return jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(rep, rep, batch_spec),
         out_specs=(rep, rep, rep),
-        check_rep=False,
+        check_vma=False,
     )

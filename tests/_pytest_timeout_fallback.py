@@ -13,8 +13,7 @@ not a shortcut: a thread wedged on an un-interruptible lock can never be
 unwound into a polite test failure.
 
 ``conftest.py`` registers this plugin only when ``import pytest_timeout``
-fails, so installing the real plugin transparently takes over (same
-pattern as `tests/_hypothesis_fallback.py`).
+fails, so installing the real plugin transparently takes over.
 """
 
 from __future__ import annotations

@@ -373,6 +373,46 @@ def test_pallas_tiles_quiet_when_annotated_and_guarded():
                 path="src/repro/kernels/fix.py") == []
 
 
+# The layout Mosaic refused on a v5e: per-point vectors in rank-1 tiles.
+_TILE_RANK1_POS = """
+from jax.experimental import pallas as pl
+
+def op(w, block_n: int = 512):  # autotune: row tile
+    assert w.shape[0] % block_n == 0
+    return pl.pallas_call(
+        lambda r, o, t: None, grid=(w.shape[0] // block_n,),
+        in_specs=[pl.BlockSpec((block_n,), lambda i: (i,))],
+        out_specs=[pl.BlockSpec(block_shape=(block_n,),
+                                index_map=lambda i: (i,)),
+                   pl.BlockSpec((1,), lambda i: (i,))],
+        out_shape=None)(w)
+"""
+
+_TILE_RANK1_NEG = """
+from jax.experimental import pallas as pl
+
+def op(w, block_n: int = 512):  # autotune: row tile
+    n = w.shape[1]
+    assert n % block_n == 0
+    return pl.pallas_call(
+        lambda r, o: None, grid=(n // block_n,),
+        in_specs=[pl.BlockSpec((1, block_n), lambda i: (0, i)),
+                  pl.BlockSpec((n,), lambda i: (0,))],
+        out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
+        out_shape=None)(w)
+"""
+
+
+@pytest.mark.parametrize("src,expected", [(_TILE_RANK1_POS, 3),
+                                          (_TILE_RANK1_NEG, 0)])
+def test_pallas_tiles_rank1_blocks(src, expected):
+    """Rank-1 tile blocks (Mosaic's layout refusal) fire; (1, block)
+    blocks and a whole-array rank-1 block do not."""
+    findings = _run(src, "pallas-tile-shape", path="src/repro/kernels/f.py")
+    assert len(findings) == expected
+    assert all("rank-1" in f.message for f in findings)
+
+
 def test_pallas_tiles_scoped_to_kernels():
     """The same source outside kernels/ is not this rule's business."""
     assert _run(_TILE_POS, "pallas-tile-shape",

@@ -1,6 +1,4 @@
-"""Property tests for the adaptive `BatchSchedule` (hypothesis; the
-deterministic fallback in tests/_hypothesis_fallback.py when the real
-library is absent).
+"""Property tests for the adaptive `BatchSchedule` (hypothesis).
 
 The contract the device programs rely on: a proposed batch is never 0,
 never exceeds the configured cap, always sits on the bucket ladder, and is

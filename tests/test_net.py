@@ -1,7 +1,6 @@
 """Wire transport suite (repro.serving.net).
 
-ISSUE 9 acceptance coverage: codec round-trip property tests (under the
-hypothesis fallback when the real library is absent), server/client
+ISSUE 9 acceptance coverage: codec round-trip property tests, server/client
 loopback bit-identity against direct `ClusterFrontend.submit`,
 tenant-quota starvation (the hot tenant throttles typed, the cold tenant
 completes), malformed-frame and mid-stream-disconnect handling with a

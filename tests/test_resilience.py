@@ -112,6 +112,12 @@ def test_classify_failure_buckets():
         == "transient"
     assert classify_failure(XlaRuntimeError("INVALID_ARGUMENT: shape")) \
         == "permanent"
+    # A refused kernel is an INTERNAL status, yet no retry can fix it.
+    assert classify_failure(XlaRuntimeError(
+        "INTERNAL: Mosaic failed to compile TPU kernel: Failed to verify "
+        "layout")) == "permanent"
+    assert classify_failure(XlaRuntimeError("INTERNAL: stream did not "
+                                            "block host")) == "transient"
     assert classify_failure(RuntimeError("mystery")) == "permanent"
 
 

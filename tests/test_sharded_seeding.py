@@ -41,7 +41,6 @@ def test_shard_sampler_distribution():
     """Shard-then-descend MULTITREESAMPLE draws each point with probability
     w_x / total across ALL shards (exactness of the top-tree + local
     descent factorisation)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = make_seeding_mesh()
@@ -61,9 +60,9 @@ def test_shard_sampler_distribution():
         idx, _, _ = sampler(coarse, w_loc, jax.random.wrap_key_data(bits), m)
         return idx
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         prog, mesh=mesh, in_specs=(P("data"), P()), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     ))
     bits = jax.random.key_data(jax.random.key(0))
     draws = np.asarray(fn(jnp.asarray(w), bits))

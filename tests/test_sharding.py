@@ -6,8 +6,8 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.distributed.sharding import DEFAULT_RULES, resolve_spec
 
-POD = AbstractMesh((("data", 16), ("model", 16)))
-MULTI = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+POD = AbstractMesh((16, 16), ("data", "model"))
+MULTI = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_basic_resolution():

@@ -23,9 +23,6 @@ The laws under test, per backend with streaming support:
   gone, the handle lives under exactly one ``#g<generation>`` key, and
   a fresh `prepare_data` of the original points is a new build, never a
   hit on the mutated stream.
-
-Runs under real `hypothesis` when installed, else the deterministic
-fallback in `tests/_hypothesis_fallback.py` (conftest installs it).
 """
 
 import numpy as np
