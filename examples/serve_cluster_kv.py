@@ -62,10 +62,13 @@ def main():
     info = {}
     if args.engine:
         from repro.core import ClusterEngine
+        from repro.core.plan import SOLVE_SPAN
+        from repro.core.tracing import span_totals
 
         # Every head is a fresh dataset submitted exactly once:
         # retain_prepared=False keeps the prepare cache at pipeline depth
         # instead of accumulating all heads' artifacts until close.
+        dispatch0 = span_totals().get(SOLVE_SPAN, {}).get("seconds", 0.0)
         with ClusterEngine(retain_prepared=False) as engine:
             cache = build_clustered_cache(keys, values, cfg, info=info,
                                           engine=engine)
@@ -73,7 +76,8 @@ def main():
         print(f"codebook rebuild via ClusterEngine x {hk} heads: "
               f"{time.time()-t0:.1f}s wall "
               f"(host prepare {st['prepare_seconds']:.1f}s overlapped with "
-              f"device solve {st['solve_seconds']:.1f}s; "
+              f"solve dispatch "
+              f"{st['spans'][SOLVE_SPAN]['seconds'] - dispatch0:.1f}s; "
               f"capacity-dropped tokens: {100*info['dropped_frac']:.2f}%)")
     else:
         cache = build_clustered_cache(keys, values, cfg, info=info)

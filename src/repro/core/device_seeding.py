@@ -57,7 +57,7 @@ import numpy as np
 from repro.core.batch_schedule import BatchSchedule, shape_bucket
 from repro.core.lsh import MonotoneLSH
 from repro.core.sample_tree import TiledSampleTree
-from repro.core.tracing import count_trace
+from repro.core.tracing import count_trace, span
 from repro.core.tree_embedding import build_multitree, compute_max_dist
 from repro.kernels.ops import (
     lsh_bucket_accept,
@@ -274,23 +274,25 @@ def prepare_rejection(
     pts = np.asarray(points, dtype=np.float64)
     n, d = pts.shape
     rng = np.random.default_rng(seed)
-    lo, hi, meta = prepare_embedding(
-        pts, seed=int(rng.integers(2 ** 31)), resolution=resolution,
-        max_dist=max_dist,
-    )
-    if lsh_r is None:
-        from repro.core.seeding import _estimate_scale
+    with span("repro.prepare.embed"):
+        lo, hi, meta = prepare_embedding(
+            pts, seed=int(rng.integers(2 ** 31)), resolution=resolution,
+            max_dist=max_dist,
+        )
+    with span("repro.prepare.lsh"):
+        if lsh_r is None:
+            from repro.core.seeding import _estimate_scale
 
-        lsh_r = 10.0 * (resolution or _estimate_scale(pts, rng))
-    lsh = MonotoneLSH(
-        d,
-        r=lsh_r,
-        num_tables=num_tables,
-        hashes_per_table=hashes_per_table,
-        seed=int(rng.integers(2 ** 31)),
-        capacity=16,
-    )
-    klo, khi = split_codes_u64(lsh.hash_keys(pts))  # (n, L) planes
+            lsh_r = 10.0 * (resolution or _estimate_scale(pts, rng))
+        lsh = MonotoneLSH(
+            d,
+            r=lsh_r,
+            num_tables=num_tables,
+            hashes_per_table=hashes_per_table,
+            seed=int(rng.integers(2 ** 31)),
+            capacity=16,
+        )
+        klo, khi = split_codes_u64(lsh.hash_keys(pts))  # (n, L) planes
     return DeviceSeedingData(
         codes_lo=lo,
         codes_hi=hi,

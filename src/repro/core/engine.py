@@ -74,6 +74,7 @@ from repro.core.resilience import (
     fallback_chain,
     validate_points,
 )
+from repro.core.tracing import add, span, span_totals
 
 __all__ = ["ClusterEngine", "FitTicket"]
 
@@ -151,8 +152,9 @@ class _Item:
     ticket: FitTicket
     plan: ClusterPlan
     points: Any
-    prep_future: cf.Future
+    prep_future: Optional[cf.Future] = None
     lane_seeds: Optional[list] = None       # None => solo request
+    prepared_at: Optional[float] = None     # when the pool's prepare ended
     # Streaming extend (`submit_extend`): the mutation is one-shot — the
     # solve worker applies it exactly once (clearing `points`) and stores
     # the mutated handle in `prep`, so retries only refit and a replayed
@@ -249,7 +251,7 @@ class ClusterEngine:
         self._cancel = False
         self._next_index = 0
         self._stats = collections.Counter()
-        self._times = {"prepare_seconds": 0.0, "solve_seconds": 0.0}
+        self._prepare_seconds = 0.0
         self._solver = threading.Thread(
             target=self._solve_loop, name="cluster-engine-solve",
             daemon=True)
@@ -313,7 +315,8 @@ class ClusterEngine:
                 raise
         return self._admit(plan, points, seed=seed, tag=tag,
                            deadline=deadline, retry=retry,
-                           prepare=lambda: self._timed_prepare(plan, points))
+                           prepare=lambda lane: self._timed_prepare(
+                               plan, points, lane))
 
     def submit_lane(self, datasets: Sequence[Any], *,
                     cluster: Optional[ClusterSpec] = None,
@@ -360,7 +363,8 @@ class ClusterEngine:
                     raise
         return self._admit(plan, datasets, seed=None, tag=tag,
                            deadline=deadline, retry=retry,
-                           prepare=lambda: self._lane_prepare(plan, datasets),
+                           prepare=lambda lane: self._lane_prepare(
+                               plan, datasets, lane),
                            lane_seeds=seeds)
 
     def submit_extend(self, points, *, prepared=None,
@@ -411,13 +415,16 @@ class ClusterEngine:
                 self._stats["extends"] += 1
         return self._admit(plan, points, seed=seed, tag=tag,
                            deadline=deadline, retry=retry,
-                           prepare=lambda: prepared, stream=True)
+                           prepare=lambda lane: prepared, stream=True)
 
     def _admit(self, plan: ClusterPlan, points, *, seed, tag, deadline,
-               retry, prepare: Callable[[], Any],
+               retry, prepare: Callable[[int], Any],
                lane_seeds: Optional[list] = None,
                stream: bool = False) -> FitTicket:
-        """Shared admission control: one queue slot per request OR lane."""
+        """Shared admission control: one queue slot per request OR lane.
+
+        `prepare(lane)` runs on the prepare pool with the ticket index.
+        """
         if deadline is not None and deadline <= 0:
             raise ValueError(f"deadline must be > 0 seconds, got {deadline}")
         shed: Optional[_Item] = None
@@ -450,9 +457,11 @@ class ClusterEngine:
                 deadline=None if deadline is None
                 else self._clock() + deadline,
                 retry=retry)
-            prep_future = self._pool.submit(prepare)
-            self._pending.append(_Item(ticket, plan, points, prep_future,
-                                       lane_seeds=lane_seeds, stream=stream))
+            item = _Item(ticket, plan, points, lane_seeds=lane_seeds,
+                         stream=stream)
+            item.prep_future = self._pool.submit(
+                self._queued_prepare, prepare, item, time.perf_counter())
+            self._pending.append(item)
             self._lock.notify_all()
         if shed is not None:
             # Outside the lock: failing the future runs done-callbacks.
@@ -519,18 +528,29 @@ class ClusterEngine:
 
     # -- pipeline internals -------------------------------------------------
 
-    def _timed_prepare(self, plan: ClusterPlan, points):
-        t0 = time.perf_counter()
-        prep = plan.prepare_data(points)
+    @staticmethod
+    def _queued_prepare(prepare: Callable[[int], Any], item: _Item,
+                        submitted: float):
+        """A prepare-pool task: counts its wait in the pool's queue and
+        stamps when it ended (the dispatch counts the wait after it)."""
+        add("engine.prepare_queue", time.perf_counter() - submitted)
+        prep = prepare(item.ticket.index)
+        item.prepared_at = time.perf_counter()
+        return prep
+
+    def _timed_prepare(self, plan: ClusterPlan, points, lane: int):
+        with span("repro.engine.prepare", lane=lane) as s:
+            prep = plan.prepare_data(points)
         with self._lock:
-            self._times["prepare_seconds"] += time.perf_counter() - t0
+            self._prepare_seconds += s.seconds
         return prep
 
     @staticmethod
     def _lane_stacked(plan: ClusterPlan) -> bool:
         return plan.impl.supports_stacked and plan.cluster.lloyd_iters == 0
 
-    def _lane_prepare(self, plan: ClusterPlan, datasets: list) -> list:
+    def _lane_prepare(self, plan: ClusterPlan, datasets: list,
+                      lane: int) -> list:
         """Prepare every lane member (stacked handles where supported).
 
         Runs as ONE prepare-pool task — members build sequentially inside
@@ -540,10 +560,10 @@ class ClusterEngine:
         """
         prep_fn = (plan.prepare_stacked if self._lane_stacked(plan)
                    else plan.prepare_data)
-        t0 = time.perf_counter()
-        preps = [prep_fn(pts) for pts in datasets]
+        with span("repro.engine.prepare", lane=lane) as s:
+            preps = [prep_fn(pts) for pts in datasets]
         with self._lock:
-            self._times["prepare_seconds"] += time.perf_counter() - t0
+            self._prepare_seconds += s.seconds
         return preps
 
     def _lane_solve(self, item: _Item, plan: ClusterPlan, preps: list,
@@ -597,7 +617,10 @@ class ClusterEngine:
                 res = self._solve_resilient(item, used)
                 with self._lock:
                     self._stats["completed"] += 1
-                item.ticket._future.set_result(res)
+                # Done-callbacks (the frontend's fan-out, the server's
+                # delivery) run here, on the solve worker.
+                with span("repro.engine.callbacks", lane=item.ticket.index):
+                    item.ticket._future.set_result(res)
             except BaseException as e:  # noqa: BLE001 — forwarded to ticket
                 with self._lock:
                     if isinstance(e, cf.CancelledError):
@@ -606,7 +629,8 @@ class ClusterEngine:
                         self._stats["failed"] += 1
                         if isinstance(e, DeadlineExceededError):
                             self._stats["deadline_expired"] += 1
-                item.ticket._future.set_exception(e)
+                with span("repro.engine.callbacks", lane=item.ticket.index):
+                    item.ticket._future.set_exception(e)
         finally:
             # Eviction must also cover failed solves, or streaming mode
             # (retain_prepared=False) leaks an entry per bad request.
@@ -682,7 +706,9 @@ class ClusterEngine:
                     # One-shot mutation: apply the extend on the first
                     # attempt only, then retries refit the mutated stream.
                     if item.prep is None:
-                        item.prep = prep_future.result()
+                        with span("repro.engine.await_prepare",
+                                  lane=ticket.index):
+                            item.prep = prep_future.result()
                     if item.points is not None:
                         item.prep = plan.extend(
                             item.points, prepared=item.prep)
@@ -690,8 +716,12 @@ class ClusterEngine:
                     prep = item.prep
                 elif prep_future is not None and attempt == 0:
                     try:
-                        prep = prep_future.result(
-                            timeout=self._remaining(ticket))
+                        with span("repro.engine.await_prepare",
+                                  lane=ticket.index):
+                            prep = prep_future.result(
+                                timeout=self._remaining(ticket))
+                        add("engine.dispatch_wait",
+                            time.perf_counter() - item.prepared_at)
                     except (cf.TimeoutError, TimeoutError):
                         if ticket.deadline is None:
                             raise      # a real timeout from inside prepare
@@ -702,9 +732,11 @@ class ClusterEngine:
                     # Retry / fallback: (re-)prepare on the solve worker.
                     # A healed transient prepare fault is a fresh build;
                     # an earlier successful build is a fingerprint hit.
-                    prep = (self._lane_prepare(plan, item.points)
+                    prep = (self._lane_prepare(plan, item.points,
+                                               ticket.index)
                             if item.lane_seeds is not None
-                            else self._timed_prepare(plan, item.points))
+                            else self._timed_prepare(plan, item.points,
+                                                     ticket.index))
                 if not self.retain_prepared and not item.stream:
                     if item.lane_seeds is not None:
                         used.extend((plan, p) for p in prep)
@@ -712,14 +744,11 @@ class ClusterEngine:
                         used.append((plan, prep))
                 self._check_cancelled()
                 self._check_deadline(ticket)
-                t0 = time.perf_counter()
                 if item.lane_seeds is not None:
                     res = self._lane_solve(item, plan, prep, attempt)
                 else:
                     res = plan.fit_prepared(
                         prep, seed=attempt_seed(ticket.seed, attempt))
-                with self._lock:
-                    self._times["solve_seconds"] += time.perf_counter() - t0
                 # A result after expiry is still an SLO miss: the caller
                 # asked for an answer *by the deadline*.
                 self._check_deadline(ticket)
@@ -789,25 +818,33 @@ class ClusterEngine:
     # -- lifecycle / stats --------------------------------------------------
 
     def stats(self) -> dict:
-        """Pipeline counters, stage seconds, and per-target health.
+        """Pipeline counters, prepare seconds, spans and per-target health.
 
         Counters in `_COUNTERS` are always present (zero-seeded);
         ``completed + failed + cancelled == submitted`` once the engine
         is closed (no stranded tickets).  ``pending`` is the
         not-yet-dispatched queue depth, ``health`` maps each
         ``"<seeder>/<backend>"`` target the engine has touched to its
-        circuit state (``OK`` / ``DEGRADED`` / ``OPEN``), and the summed
-        host-prepare / device-solve stage seconds quantify the
-        pipelining win (serial wall-clock would be their sum).
+        circuit state (``OK`` / ``DEGRADED`` / ``OPEN``), and
+        ``prepare_seconds`` sums this engine's host prepares.  ``spans``
+        is the process-wide `repro.core.tracing.span_totals()`: among
+        them ``repro.plan.solve`` (host dispatch of each solve; the
+        device finishes later), ``repro.engine.await_prepare`` (the
+        solve worker waiting on a prepare), ``repro.engine.callbacks``
+        (result delivery run on the solve worker) and the counter
+        ``engine.prepare_queue`` (a prepare task's wait for a pool
+        worker) and ``engine.dispatch_wait`` (a prepared request's wait
+        for the solve worker).
         """
         out = {k: 0 for k in _COUNTERS}
         with self._lock:
             out.update(self._stats)
-            out.update(self._times)
+            out["prepare_seconds"] = self._prepare_seconds
             out["plans"] = len(self._plans)
             out["pending"] = len(self._pending)
             out["health"] = {f"{s}/{b}": br.state
                              for (s, b), br in self._breakers.items()}
+        out["spans"] = span_totals()
         return out
 
     def close(self, wait: bool = True, *,

@@ -60,6 +60,7 @@ from repro.core.batch_schedule import BatchSchedule
 from repro.core.lloyd import lloyd
 from repro.core.preprocess import quantize
 from repro.core.registry import BACKENDS, get_seeder_spec
+from repro.core.tracing import span
 
 __all__ = [
     "ClusterSpec",
@@ -94,6 +95,11 @@ def ensure_host_f64(points) -> np.ndarray:
         return arr
     return np.ascontiguousarray(arr, dtype=np.float64)
 
+
+#: Span of every public solve entry (`refit`, `fit_prepared`, `fit_batch`,
+#: `fit_batch_prepared`): from entry until the device-resident result is
+#: returned, i.e. host dispatch; the device finishes later.
+SOLVE_SPAN = "repro.plan.solve"
 
 _FULL_HASH_BYTES = 1 << 22          # full-hash threshold for device arrays
 _SAMPLE_ROWS = 4096
@@ -750,11 +756,13 @@ class ClusterPlan:
         in one pass, so only the quantisation is cached for them and each
         refit rebuilds its tree/LSH structures.
         """
-        with self._lock:
-            active = self._active
-        if active is None:
-            raise RuntimeError("refit() needs a prior prepare()/fit(points)")
-        return self._execute(active, k or self.cluster.k, seed)
+        with span(SOLVE_SPAN):
+            with self._lock:
+                active = self._active
+            if active is None:
+                raise RuntimeError(
+                    "refit() needs a prior prepare()/fit(points)")
+            return self._execute(active, k or self.cluster.k, seed)
 
     def fit_prepared(self, prepared: PreparedData, *,
                      k: Optional[int] = None,
@@ -768,11 +776,16 @@ class ClusterPlan:
         the prepare-time rng snapshot is replayed, so the result is
         bit-for-bit the serial `prepare(points); fit()` sequence.
         """
+        with span(SOLVE_SPAN):
+            return self._solve_prepared(prepared, k or self.cluster.k, seed)
+
+    def _solve_prepared(self, prepared: PreparedData, k: int,
+                        seed: Optional[int]) -> FitResult:
         # Keyed by fingerprint only (not the solve seed): retries of one
         # request hit the same key, so FaultPlan's per-key failure caps
         # model a transient fault that heals on re-attempt.
         self._fault_inject("solve", prepared.fingerprint)
-        return self._execute(prepared, k or self.cluster.k, seed)
+        return self._execute(prepared, k, seed)
 
     def _solve_rng(self, prep: PreparedData,
                    seed: Optional[int]) -> np.random.Generator:
@@ -914,20 +927,24 @@ class ClusterPlan:
           indices/centers/cost are reported per lane in each dataset's
           ORIGINAL coordinates.
         """
-        if datasets is not None:
-            if points is not None:
-                raise ValueError("pass either points= or datasets=, not both")
-            return self._fit_batch_datasets(list(datasets), seeds)
-        if seeds is None:
-            raise ValueError("fit_batch() needs seeds (or datasets=...)")
-        prep = self._require(points)
-        seeds = [int(s) for s in seeds]
-        if not seeds:
-            raise ValueError("fit_batch() needs at least one seed")
-        if (self.impl.device_native and self._ctx.backend == "device"
-                and self.cluster.lloyd_iters == 0):
-            return self._fit_batch_vmapped(prep, seeds)
-        return _stack_results([self.refit(seed=s) for s in seeds], seeds)
+        with span(SOLVE_SPAN):
+            if datasets is not None:
+                if points is not None:
+                    raise ValueError(
+                        "pass either points= or datasets=, not both")
+                return self._fit_batch_datasets(list(datasets), seeds)
+            if seeds is None:
+                raise ValueError("fit_batch() needs seeds (or datasets=...)")
+            prep = self._require(points)
+            seeds = [int(s) for s in seeds]
+            if not seeds:
+                raise ValueError("fit_batch() needs at least one seed")
+            if (self.impl.device_native and self._ctx.backend == "device"
+                    and self.cluster.lloyd_iters == 0):
+                return self._fit_batch_vmapped(prep, seeds)
+            return _stack_results(
+                [self._execute(prep, self.cluster.k, s) for s in seeds],
+                seeds)
 
     def _fit_batch_vmapped(self, prep: PreparedData,
                            seeds: list[int]) -> FitResult:
@@ -992,8 +1009,8 @@ class ClusterPlan:
             # fingerprint-cached; the engine is the pipelined alternative).
             results = []
             for pts_i, s in zip(datasets, seeds):
-                results.append(
-                    self.fit_prepared(self.prepare_data(pts_i), seed=s))
+                results.append(self._solve_prepared(
+                    self.prepare_data(pts_i), self.cluster.k, s))
             out = _stack_results(results, seeds)
             out.extras["stacked"] = False
             return out
@@ -1003,7 +1020,7 @@ class ClusterPlan:
                            seeds: list[int]) -> FitResult:
         preps = [self._prepare_cached(pts_i, stacked=True)
                  for pts_i in datasets]
-        return self.fit_batch_prepared(preps, seeds=seeds)
+        return self._fit_batch_prepared(preps, seeds)
 
     def fit_batch_prepared(self, prepared: Sequence[PreparedData], *,
                            seeds: Optional[Sequence[int]] = None
@@ -1020,6 +1037,11 @@ class ClusterPlan:
         continuous-batching front-end's coalescing rests on.  `seeds`
         defaults to the spec seed per lane (the solo `refit` stream).
         """
+        with span(SOLVE_SPAN):
+            return self._fit_batch_prepared(prepared, seeds)
+
+    def _fit_batch_prepared(self, prepared: Sequence[PreparedData],
+                            seeds: Optional[Sequence[int]]) -> FitResult:
         t0 = time.perf_counter()
         preps = list(prepared)
         if not preps:

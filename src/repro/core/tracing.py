@@ -7,14 +7,29 @@ static configuration must reuse the compiled program, i.e. leave every
 counter untouched.  Tests assert exactly that, for the single-device
 programs (keys ``"<seeder>/device"``) and the shard_map programs (bare
 ``"<seeder>"`` keys, kept for backward compatibility with the PR-3 tests).
+
+Spans time the host side of the served path at its layer boundaries.
+`span(name, **ids)` opens a `jax.profiler.TraceAnnotation` of the same
+name, so a profiled run shows it on the host plane on the device trace's
+clock, and on exit adds its duration and one count to a process-wide
+running total; `add(name, seconds)` adds an interval measured across
+threads, which no thread-scoped span can hold.  `span_totals()` is the
+snapshot `ClusterEngine.stats()` reports under ``"spans"``.  The totals
+are always on (two clock reads and one lock per span); spans belong in
+host code only, never inside a jit body.
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
+import threading
+import time
 
-__all__ = ["TRACE_COUNTS", "count_trace", "no_retrace", "RetraceError"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["TRACE_COUNTS", "count_trace", "no_retrace", "RetraceError",
+           "span", "add", "span_totals"]
 
 TRACE_COUNTS: collections.Counter = collections.Counter()
 
@@ -76,3 +91,56 @@ def no_retrace(*, watch: tuple = (), allow: tuple = ()):
             deltas[name] = grew
     if deltas:
         raise RetraceError(deltas)
+
+
+_SPAN_LOCK = threading.Lock()
+_SPAN_TOTALS: dict = {}            # name -> [seconds, count]
+
+
+def add(name: str, seconds: float) -> None:
+    """Add one interval of `seconds` to the running total of `name`."""
+    with _SPAN_LOCK:
+        rec = _SPAN_TOTALS.get(name)
+        if rec is None:
+            _SPAN_TOTALS[name] = [seconds, 1]
+        else:
+            rec[0] += seconds
+            rec[1] += 1
+
+
+class Span:
+    """One timed span (see `span`); `seconds` is its duration once closed."""
+
+    __slots__ = ("name", "seconds", "_annotation", "_t0")
+
+    def __init__(self, name: str, ids: dict):
+        self.name = name
+        self.seconds = 0.0
+        self._annotation = TraceAnnotation(name, **ids)
+
+    def __enter__(self) -> "Span":
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+        add(self.name, self.seconds)
+
+
+def span(name: str, **ids) -> Span:
+    """Context manager timing its body as span `name` (also when it raises).
+
+    `ids` ride on the profiler event as metadata (``lane=`` an engine
+    ticket index, ``rid=`` a wire request id), so the spans of one lane
+    or request can be joined in a trace.
+    """
+    return Span(name, ids)
+
+
+def span_totals() -> dict:
+    """Snapshot of every span's running ``{"seconds", "count"}``."""
+    with _SPAN_LOCK:
+        return {name: {"seconds": rec[0], "count": rec[1]}
+                for name, rec in _SPAN_TOTALS.items()}

@@ -60,4 +60,5 @@ def d2_update_pallas(
         out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
+        name="d2_update_pallas",
     )(x, center.reshape(1, -1), w)

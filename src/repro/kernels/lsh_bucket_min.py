@@ -138,6 +138,7 @@ def lsh_bucket_min_pallas(
         out_specs=pl.BlockSpec((1, block_b), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, b), jnp.float32),
         interpret=interpret,
+        name="lsh_bucket_min_pallas",
     )(q_keys_lo, q_keys_hi, q, c_keys_lo, c_keys_hi, c, penalty)
 
 
@@ -191,4 +192,5 @@ def lsh_bucket_accept_pallas(
             jax.ShapeDtypeStruct((1, b), jnp.float32),
         ],
         interpret=interpret,
+        name="lsh_bucket_accept_pallas",
     )(q_keys_lo, q_keys_hi, q, c_keys_lo, c_keys_hi, c, penalty, mtd2)

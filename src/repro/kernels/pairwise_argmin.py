@@ -96,4 +96,5 @@ def pairwise_argmin_pallas(
             jax.ShapeDtypeStruct((1, n), jnp.int32),
         ],
         interpret=interpret,
+        name="pairwise_argmin_pallas",
     )(x, c)

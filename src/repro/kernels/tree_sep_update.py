@@ -80,4 +80,5 @@ def tree_sep_update_pallas(
         out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
+        name="tree_sep_update_pallas",
     )(codes_lo, codes_hi, center_lo.reshape(-1, 1), center_hi.reshape(-1, 1), w)

@@ -44,6 +44,7 @@ import time
 from typing import Any, Callable, Optional, Tuple
 
 from repro.core import ClusterSpec, ExecutionSpec
+from repro.core.tracing import span
 from repro.serving.frontend import ClusterFrontend
 from repro.serving.net.protocol import (
     ChunkFrame,
@@ -379,7 +380,9 @@ class ClusterServer:
                     self._counters["errors_sent"] += 1
                 conn.send_error(rid, exc)
                 return
-            res = ticket.result().to_numpy()
+            # Waits for the device to finish the lane, then copies.
+            with span("repro.net.fetch", rid=rid):
+                res = ticket.result().to_numpy()
             queue_wait = float(res.extras.get("queue_wait", 0.0))
             extras = dict(res.extras)
             extras["server"] = {
