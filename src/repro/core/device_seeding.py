@@ -6,7 +6,10 @@ The pointer-machine data structures become arrays (DESIGN.md §3):
   - MULTITREEOPEN is the fused `tree_sep_update` Pallas kernel per tree
     (compare+reduce+min over all points: O(nH) VPU work, no pointers); the
     *last* tree's sweep uses the `_tiles` wrapper, which also returns the
-    per-tile weight sums;
+    per-tile weight sums.  The kernel wants heights padded to a multiple
+    of 8 and points to the tile; the codes never change during a seeding,
+    so they are padded and split into per-tree planes once, before the
+    k-center loop (`_make_open_center`), and no sweep re-pads them;
   - MULTITREESAMPLE is the two-level `TiledSampleTree` descent: a coarse
     flat heap over the T = n/tile tile sums plus one vectorised intra-tile
     cumsum.  After each opened center the coarse heap is fixed *in place*
@@ -61,6 +64,7 @@ from repro.core.tracing import count_trace, span
 from repro.core.tree_embedding import build_multitree, compute_max_dist
 from repro.kernels.ops import (
     lsh_bucket_accept,
+    pad_tree_codes,
     pairwise_argmin,
     split_codes_u64,
     tree_sep_update,
@@ -123,22 +127,34 @@ def _make_open_center(codes_lo, codes_hi, *, scale, num_levels, tile,
                       interpret):
     """Per-center fused sweep over all trees; the last tree's kernel emits
     the per-tile weight sums the coarse heap update consumes (one pass over
-    the weight vector, not over the points)."""
-    t = codes_lo.shape[0]
+    the weight vector, not over the points).
+
+    Call it outside the per-center loop: it pads and splits the (T, H-1, n)
+    codes here, once (`ops.pad_tree_codes`), and the returned sweep closes
+    over the T per-tree (H8, n_pad) planes, so the loop body slices and
+    pads no code plane.  The opened center's column is read from the first
+    H-1 rows only: the wrapper pads it with the center sentinel -2, where
+    the planes carry the point sentinel -1.  Read from all H8 rows it would
+    carry -1 there, match every point on the pad rows and raise each
+    point's `sep` by the pad height.
+    """
+    h = codes_lo.shape[1]
+    planes = list(zip(pad_tree_codes(codes_lo, block_n=tile),
+                      pad_tree_codes(codes_hi, block_n=tile)))
+
+    def column(plane, x):
+        return jax.lax.dynamic_slice(plane, (0, x), (h, 1))[:, 0]
 
     def open_center(weights, x):
-        for ti in range(t - 1):
+        for lo, hi in planes[:-1]:
             weights = tree_sep_update(
-                codes_lo[ti], codes_hi[ti],
-                codes_lo[ti, :, x], codes_hi[ti, :, x],
-                weights,
+                lo, hi, column(lo, x), column(hi, x), weights,
                 scale=scale, num_levels=num_levels, block_n=tile,
                 interpret=interpret,
             )
+        lo, hi = planes[-1]
         return tree_sep_update_tiles(
-            codes_lo[t - 1], codes_hi[t - 1],
-            codes_lo[t - 1, :, x], codes_hi[t - 1, :, x],
-            weights,
+            lo, hi, column(lo, x), column(hi, x), weights,
             scale=scale, num_levels=num_levels, block_n=tile,
             interpret=interpret,
         )
@@ -195,9 +211,7 @@ def device_fast_kmeanspp(
     t, h, n = codes_lo.shape
     live = n if n_real is None else n_real
     ts = TiledSampleTree(n, tile=tile)
-    clo = _pad_axis(codes_lo, 2, ts.n_pad)
-    chi = _pad_axis(codes_hi, 2, ts.n_pad)
-    open_center = _make_open_center(clo, chi, scale=scale,
+    open_center = _make_open_center(codes_lo, codes_hi, scale=scale,
                                     num_levels=num_levels, tile=tile,
                                     interpret=interpret)
 
@@ -391,12 +405,10 @@ def device_rejection_sampling(
     buckets = schedule.buckets()
     b_idx0 = schedule.index_of(schedule.initial(n, k, ts.num_tiles))
 
-    clo = _pad_axis(codes_lo, 2, ts.n_pad)
-    chi = _pad_axis(codes_hi, 2, ts.n_pad)
     pts_pad = _pad_axis(points, 0, ts.n_pad)
     klo_pad = _pad_axis(keys_lo, 1, ts.n_pad)
     khi_pad = _pad_axis(keys_hi, 1, ts.n_pad)
-    open_center = _make_open_center(clo, chi, scale=scale,
+    open_center = _make_open_center(codes_lo, codes_hi, scale=scale,
                                     num_levels=num_levels, tile=tile,
                                     interpret=interpret)
 
@@ -438,7 +450,7 @@ def device_rejection_sampling(
                         jnp.take(klo_pad, cand, axis=1),
                         jnp.take(khi_pad, cand, axis=1),
                         jnp.take(pts_pad, cand, axis=0),
-                        ck_lo, ck_hi, ctr_pts, mtd2, i,
+                        ck_lo.T, ck_hi.T, ctr_pts, mtd2, i,
                         c2=c2, interpret=interpret,
                     )
                     acc = us < p_acc
@@ -477,16 +489,21 @@ def device_rejection_sampling(
         coarse = ts.refresh(coarse, tsums)
         chosen = chosen.at[i].set(x)
         ctr_pts = ctr_pts.at[i].set(pts_pad[x])
-        ck_lo = ck_lo.at[:, i].set(klo_pad[:, x])
-        ck_hi = ck_hi.at[:, i].set(khi_pad[:, x])
+        ck_lo = ck_lo.at[i].set(klo_pad[:, x])
+        ck_hi = ck_hi.at[i].set(khi_pad[:, x])
         trials = trials.at[i].set(t_i)
         return (weights, coarse, chosen, ctr_pts, ck_lo, ck_hi, trials,
                 b_idx, acc_ema, key)
 
     chosen0 = jnp.zeros((k,), jnp.int32)
     ctr_pts0 = jnp.full((k, d), _FAR, jnp.float32)
-    ck_lo0 = jnp.zeros((l, k), jnp.int32)
-    ck_hi0 = jnp.zeros((l, k), jnp.int32)
+    # The opened centers' bucket keys, one row per center (the kernel takes
+    # them transposed).  Rows, not columns: a column write can make XLA hold
+    # the (L, n) key planes in a second layout on the TPU and copy both into
+    # it for every opened center, where a row write reads the center's keys
+    # in the layout the candidate gather already wants.
+    ck_lo0 = jnp.zeros((k, l), jnp.int32)
+    ck_hi0 = jnp.zeros((k, l), jnp.int32)
     trials0 = jnp.zeros((k,), jnp.int32)
     out = jax.lax.fori_loop(
         0, k, body,

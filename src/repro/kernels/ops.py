@@ -31,6 +31,7 @@ __all__ = [
     "d2_update_tiles",
     "tree_sep_update",
     "tree_sep_update_tiles",
+    "pad_tree_codes",
     "lsh_bucket_min",
     "lsh_bucket_accept",
     "LSH_MISS",
@@ -133,17 +134,27 @@ def d2_update_tiles(
     return out, _tile_sums(out, block_n)
 
 
+def _pad_tree_plane(codes, block_n):
+    """(H, n) codes -> (H8, n_pad): points padded with 0, heights with the
+    point sentinel -1."""
+    return _pad_to(_pad_to(codes, 1, block_n, 0), 0, 8, -1)
+
+
 def _tree_sep_padded(codes_lo, codes_hi, center_lo, center_hi, w, *, scale,
                      num_levels, block_n, interpret):
     """The tree sweep on block-padded inputs; returns the padded w'.
 
     Height padding (to a sublane multiple of 8) uses codes that can never
-    match (-1 vs -2), so padded heights contribute nothing to `sep`.
+    match (-1 vs -2), so padded heights contribute nothing to `sep`.  Every
+    pad here is a no-op on planes from `pad_tree_codes`: the seeders pad
+    those once per seeding, outside their per-center loop, and hand in the
+    center's column at its real height, so only that column (here, with
+    -2) is padded per sweep.
     """
     if interpret is None:
         interpret = default_interpret()
-    lo = _pad_to(_pad_to(codes_lo, 1, block_n, 0), 0, 8, -1)
-    hi = _pad_to(_pad_to(codes_hi, 1, block_n, 0), 0, 8, -1)
+    lo = _pad_tree_plane(codes_lo, block_n)
+    hi = _pad_tree_plane(codes_hi, block_n)
     clo = _pad_to(center_lo, 0, 8, -2)
     chi = _pad_to(center_hi, 0, 8, -2)
     wp = _pad_to(w, 0, block_n, 0.0)
@@ -195,6 +206,23 @@ def tree_sep_update_tiles(
                            scale=scale, num_levels=num_levels,
                            block_n=block_n, interpret=interpret)
     return out, _tile_sums(out, block_n)
+
+
+def pad_tree_codes(codes: jax.Array, *,
+                   block_n: int) -> tuple[jax.Array, ...]:
+    """(T, H-1, n) code plane -> T per-tree (H8, n_pad) planes, padded as
+    `tree_sep_update` pads them: points to a multiple of `block_n` with 0,
+    heights to a multiple of 8 with the point sentinel -1.
+
+    Codes never change during a seeding, so the device seeders call this
+    once, before their per-center loop, and sweep the result: the loop
+    then slices and pads no code plane.  Each plane stays its own array
+    (one kernel operand per tree, not a stacked operand), and a center's
+    column must be taken from the first H-1 rows, so the wrapper pads it
+    with the center sentinel -2.
+    """
+    return tuple(_pad_tree_plane(codes[ti], block_n)
+                 for ti in range(codes.shape[0]))
 
 
 def lsh_bucket_min(

@@ -2,19 +2,27 @@
 cross-checked against the faithful CPU implementation (Pallas kernels in
 interpret mode, so everything here runs on CPU)."""
 
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 
 from repro.core import KMeansConfig, fit, resolve_seeder
+from repro.core.batch_schedule import BatchSchedule
 from repro.core.device_seeding import (
+    _canonical_rejection_lane,
+    device_fast_kmeanspp,
     device_rejection_sampling,
     device_rejection_seeder,
     prepare_rejection,
+    stacked_rejection_sampling,
 )
 from repro.core.lsh import MonotoneLSH
+from repro.core.plan import _batched_rejection
 from repro.core.seeding import SEEDERS, clustering_cost, rejection_sampling
 from repro.kernels import ops, ref
 from repro.kernels.lsh_bucket_min import LSH_MISS
@@ -173,3 +181,185 @@ def test_fit_facade_device_backend():
         resolve_seeder("kmeans++", "device")
     with pytest.raises(KeyError):
         resolve_seeder("rejection", "gpu")
+
+
+# ---------------------------------------------------------------------------
+# The per-center loop sweeps code planes padded and split once, before it.
+# Shapes chosen so that every pad is real: n = 700 is not a multiple of the
+# 128 tile, and the 13-level embedding has 12 code heights, not 8 or 16.
+# ---------------------------------------------------------------------------
+
+_TILE, _K = 128, 9
+
+
+@pytest.fixture(scope="module")
+def awkward():
+    rng = np.random.default_rng(7)
+    ctr = rng.normal(size=(12, 5)) * 10
+    pts = ctr[rng.integers(12, size=700)] + rng.normal(size=(700, 5))
+    data = prepare_rejection(pts, seed=3, resolution=0.05)
+    assert data.codes_lo.shape == (3, 12, 700)
+    return data
+
+
+def _awkward_kw(data, **extra):
+    return dict(scale=data.scale, num_levels=data.num_levels,
+                m_init=data.m_init, tile=_TILE, interpret=True, **extra)
+
+
+def _rejection_kw(data):
+    return _awkward_kw(data, c=2.0, schedule=BatchSchedule(), max_rounds=32)
+
+
+def _nested_jaxprs(params):
+    for v in params.values():
+        for p in v if isinstance(v, (tuple, list)) else (v,):
+            if isinstance(p, jax.extend.core.ClosedJaxpr):
+                yield p.jaxpr
+            elif isinstance(p, jax.extend.core.Jaxpr):
+                yield p
+
+
+def _eqns(jaxpr):
+    """Every equation of `jaxpr` and of the jaxprs nested in it, outer
+    first; kernel bodies are not entered."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in _nested_jaxprs(eqn.params):
+                yield from _eqns(sub)
+
+
+def _code_plane_ops(eqns, h, n):
+    """Equations that make an int32 (heights, points) plane: heights H-1
+    or padded to 8, points n or padded to the tile."""
+    heights = {h, -(-h // 8) * 8}
+    points = {n, -(-n // _TILE) * _TILE}
+    return [e.primitive.name for e in eqns
+            for v in e.outvars
+            if v.aval.dtype == jnp.int32 and v.aval.ndim >= 2
+            and v.aval.shape[-2] in heights and v.aval.shape[-1] in points]
+
+
+_LOOP_BODY = {"while": "body_jaxpr", "scan": "jaxpr"}
+
+
+def _split_at_center_loop(closed):
+    """(equations outside the per-center loop, equations inside it): the
+    loop is the outermost `fori_loop` (a `while` or a `scan`) whose body
+    runs a kernel."""
+    eqns = list(_eqns(closed.jaxpr))
+    for i, eqn in enumerate(eqns):
+        if eqn.primitive.name not in _LOOP_BODY:
+            continue
+        body = list(_eqns(eqn.params[_LOOP_BODY[eqn.primitive.name]].jaxpr))
+        if any(e.primitive.name == "pallas_call" for e in body):
+            return eqns[:i], body
+    raise AssertionError("no per-center loop found")
+
+
+@pytest.mark.parametrize("seeder", ["rejection", "fastkmeans++"])
+def test_center_loop_pads_and_slices_no_code_plane(awkward, seeder):
+    """The codes are padded and split per tree before the `fori_loop`: the
+    loop body makes no (H8, n_pad) or (H-1, n_pad) int32 plane, by pad,
+    slice or anything else, while the program does make them before it."""
+    d = awkward
+    key = jax.random.key(0)
+    if seeder == "rejection":
+        closed = jax.make_jaxpr(lambda *a: device_rejection_sampling(
+            *a, _K, key, **_rejection_kw(d)))(
+            d.codes_lo, d.codes_hi, d.points, d.keys_lo, d.keys_hi)
+    else:
+        closed = jax.make_jaxpr(lambda lo, hi: device_fast_kmeanspp(
+            lo, hi, _K, key, **_awkward_kw(d)))(d.codes_lo, d.codes_hi)
+    before, body = _split_at_center_loop(closed)
+    h, n = d.codes_lo.shape[1:]
+    assert "pad" in _code_plane_ops(before, h, n)
+    assert _code_plane_ops(body, h, n) == []
+
+
+# (indices, trials) per key 0, 1, 2, recorded before the code planes were
+# padded outside the loop: the draws must not move.
+_SOLO = [
+    ([222, 313, 588, 275, 280, 632, 134, 145, 176], [1, 1, 1, 1, 1, 1, 1, 4, 3]),
+    ([228, 302, 460, 528, 219, 137, 285, 181, 515], [1, 1, 2, 1, 2, 3, 1, 1, 2]),
+    ([687, 423, 429, 155, 99, 383, 540, 415, 443], [1, 1, 2, 2, 2, 1, 3, 4, 2]),
+]
+_W0 = [
+    ([395, 304, 584, 127, 281, 247, 252, 531, 307], [1, 1, 1, 2, 1, 2, 2, 1, 1]),
+    ([27, 429, 147, 526, 335, 494, 285, 191, 516], [1, 1, 1, 1, 2, 1, 1, 1, 6]),
+    ([337, 430, 556, 154, 614, 382, 352, 577, 399], [1, 1, 1, 2, 1, 1, 1, 2, 1]),
+]
+_FASTKMEANSPP = [
+    [222, 28, 635, 112, 672, 641, 20, 665, 174],
+    [228, 489, 443, 266, 218, 510, 594, 11, 203],
+    [687, 597, 376, 145, 411, 645, 502, 66, 604],
+]
+# Two lanes of 700 and 650 rows (seeds 7, 8), canonical prepare, keys 0, 1.
+_STACKED = (
+    [[222, 311, 687, 275, 51, 387, 250, 693, 255],
+     [478, 295, 594, 531, 281, 502, 625, 172, 419]],
+    [[1, 1, 3, 1, 3, 7, 2, 8, 32], [1, 1, 3, 1, 4, 1, 8, 4, 3]],
+)
+
+
+def _draws(idx, trials):
+    return np.asarray(idx).tolist(), np.asarray(trials).tolist()
+
+
+@pytest.mark.parametrize("path", ["solo", "batched", "w0"])
+def test_padded_sweeps_keep_the_draws(awkward, path):
+    """Solo, vmapped (`fit_batch`) and streaming (`w0`) programs open the
+    same centers after the same trials as before the change.  A center
+    column that carried the points' -1 on the pad heights would match every
+    point there and move the weights, hence the draws."""
+    d = awkward
+    args = (d.codes_lo, d.codes_hi, d.points, d.keys_lo, d.keys_hi, _K)
+    keys = [jax.random.key(s) for s in range(3)]
+    if path == "batched":
+        bits = jnp.stack([jax.random.key_data(k) for k in keys])
+        idx, trials = _batched_rejection(*args, bits, **_rejection_kw(d))
+        got = list(zip(*_draws(idx, trials)))
+        assert got == [tuple(x) for x in _SOLO]
+        return
+    extra = {}
+    want = _SOLO
+    if path == "w0":
+        extra["w0"] = jnp.where(jnp.arange(700) % 5 == 3, 0.0,
+                                d.m_init).astype(jnp.float32)
+        want = _W0
+    for key, (idx_w, trials_w) in zip(keys, want):
+        got = _draws(*device_rejection_sampling(
+            *args, key, **_rejection_kw(d), **extra))
+        assert got == (idx_w, trials_w)
+
+
+def test_padded_sweeps_keep_the_draws_fastkmeanspp(awkward):
+    d = awkward
+    for s, want in enumerate(_FASTKMEANSPP):
+        idx = device_fast_kmeanspp(d.codes_lo, d.codes_hi, _K,
+                                   jax.random.key(s), **_awkward_kw(d))
+        assert np.asarray(idx).tolist() == want
+
+
+def test_padded_sweeps_keep_the_draws_stacked():
+    """The per-lane-data vmapped program (`fit_batch(datasets=...)`): lanes
+    of 700 and 650 rows in one 1024-row bucket."""
+    def lane(seed, n, rng_seed):
+        rng = np.random.default_rng(seed)
+        ctr = rng.normal(size=(12, 5)) * 10
+        pts = ctr[rng.integers(12, size=n)] + rng.normal(size=(n, 5))
+        return _canonical_rejection_lane(
+            pts, np.random.default_rng(rng_seed), options={},
+            execution=SimpleNamespace(tile=_TILE))
+
+    lanes = [lane(7, 700, 3), lane(8, 650, 4)]
+    arrs = [jnp.stack([ln.arrays[j] for ln in lanes]) for j in range(5)]
+    n_real = jnp.asarray([ln.n_real for ln in lanes], jnp.int32)
+    scale, num_levels, m_init = lanes[0].statics
+    bits = jnp.stack([jax.random.key_data(jax.random.key(s)) for s in (0, 1)])
+    idx, trials = stacked_rejection_sampling(
+        *arrs, n_real, bits, k=_K, scale=scale, num_levels=num_levels,
+        m_init=m_init, c=2.0, schedule=BatchSchedule(), max_rounds=32,
+        tile=_TILE, interpret=True)
+    assert _draws(idx, trials) == _STACKED

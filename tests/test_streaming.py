@@ -53,7 +53,7 @@ def _points(seed: int, n: int) -> np.ndarray:
 
 # -- scratch equivalence -----------------------------------------------------
 
-@settings(max_examples=5)
+@settings(max_examples=5, deadline=None)
 @given(st.integers(0, 2), st.integers(8, 32), st.integers(1, 12),
        st.integers(0, 10_000))
 def test_extend_duplicates_matches_scratch(backend_i, n_a, n_b, seed):
@@ -108,7 +108,7 @@ def test_extend_duplicates_matches_scratch(backend_i, n_a, n_b, seed):
 
 # -- retire round-trip -------------------------------------------------------
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(st.integers(4, 48), st.integers(1, 24), st.integers(0, 10_000))
 def test_extend_then_retire_roundtrips_weights(n_a, n_b, seed):
     """Extend-then-retire of the same rows restores `w0`/`base_heap`
