@@ -125,7 +125,7 @@ def run_cell(args, reg: Registry, *, devices, t_start=T_START) -> dict:
     counter = CompileCounter()
 
     driver = reg.module("traffic", traffic["kind"]).Driver(
-        config, traffic, args.seed, log)
+        config, traffic, args.seed, log, used)
     driver.setup()
     setup_s = time.perf_counter() - t_start
     log(f"setup: {setup_s:.3f}s ({counter.builds} programs built in "
@@ -178,6 +178,7 @@ def run_cell(args, reg: Registry, *, devices, t_start=T_START) -> dict:
         log(f"cost: ratio {ratio!r} to k-means++ (mean reference cost "
             f"{ref_cost!r}); reported cost off float64 by at most {gap!r}; "
             f"{time.perf_counter() - t_check:.3f}s")
+    driver.free_points_dev()
     from repro.kernels import ops
 
     kerr = kernel_check.kernel_errors(

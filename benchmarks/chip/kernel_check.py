@@ -9,6 +9,16 @@ distance: their errors are relative to that scale.  The others are
 relative to the reference value.  A float32 kernel reads ~1e-7; one pass
 of bfloat16 (8 mantissa bits) reads ~1e-3.
 
+At most `KERNEL_ROWS` rows are checked.  A larger set is checked on a
+sorted sample of that many rows, drawn from a stream of the run's seed
+that nothing else draws from, at the cell's widths (d, k, H, L, B).  The
+number of rows is not a width: the sharded program calls these kernels on
+one shard at a time, so a sample no larger than a shard checks what the
+window ran, and the check's float32 copy of the rows (lane-padded on the
+chip) stays at about half a GB on the first chip whatever the cell's n.
+A set of at most `KERNEL_ROWS` rows is checked whole, and `seed`'s own
+stream is drawn from in the same order either way.
+
 `ops` is the module whose kernels are checked: the program's
 `repro.kernels.ops`, or a stand-in (the precision control).
 """
@@ -17,9 +27,11 @@ from __future__ import annotations
 
 import numpy as np
 
-__all__ = ["kernel_errors", "LSH_MISS_FLOOR"]
+__all__ = ["kernel_errors", "KERNEL_ROWS", "LSH_MISS_FLOOR"]
 
 LSH_MISS_FLOOR = 1.0e30      # a kernel output at or above this is a miss
+KERNEL_ROWS = 2 ** 20        # most rows checked (see the module docstring)
+ROW_STREAM = 6               # the seed's stream of the row sample
 
 
 def _d2_f64(x, c, chunk=32768):
@@ -38,6 +50,10 @@ def kernel_errors(ops, x64: np.ndarray, *, k: int, h: int, l: int, b: int,
     import jax.numpy as jnp
 
     rng = np.random.default_rng(seed)
+    if len(x64) > KERNEL_ROWS:
+        sample = np.random.default_rng([int(seed), ROW_STREAM]).choice(
+            len(x64), KERNEL_ROWS, replace=False)
+        x64 = x64[np.sort(sample)]
     n, d = x64.shape
     x = jnp.asarray(x64, jnp.float32)
     xf = np.asarray(x, np.float64)          # the inputs the chip sees

@@ -9,6 +9,12 @@ the table is an error).  A kernel whose matrix products run at a float32
 precision (its module's `PRECISION`) takes that many bfloat16 passes at
 the bfloat16 peak, so its operations count once per pass.  The share is
 the sum of those least times over the kernel's device time in the trace.
+
+A share is per chip.  On a trace of several chips the calls of every chip
+are counted, and `xplane` keeps each op's time as the mean over the
+chips, so the least times are divided by that mean times the number of
+chips: the device time of all chips together.  A call that each of four
+chips runs in t seconds reads what the same call on one chip reads.
 """
 
 from __future__ import annotations
@@ -42,4 +48,4 @@ def share(run, kernel: str):
         ops, nbytes = mod.cost(xplane.parse_call(text))
         least += calls * max(passes * ops / peak["flops_per_s"],
                              nbytes / peak["hbm_bytes_per_s"])
-    return 100.0 * least / op.seconds
+    return 100.0 * least / (op.seconds * run.trace.chips)

@@ -1,9 +1,12 @@
-"""A copy of the benchmark with a tiny configuration added as new files.
+"""A copy of the benchmark with tiny configurations added as new files.
 
 The copy holds `BENCHMARK.json` and `benchmarks/chip` as committed, plus
-`configs/tiny.json`, one cell per traffic mix (a stacked batch of 8 and a
-served mix sized for the CPU among them), each a new file, and their entries in the copy's
-`BENCHMARK.json`.  No file the benchmark already has is edited.
+`configs/tiny.json` with one cell per traffic mix (a stacked batch of 8
+and a served mix sized for the CPU among them), and
+`configs/tiny-sharded.json` (`rejection/sharded`, n not a multiple of 4)
+with a re-seeding cell on four chips, each a new file, and their entries
+in the copy's `BENCHMARK.json`.  No file the benchmark already has is
+edited.
 """
 
 from __future__ import annotations
@@ -23,6 +26,7 @@ TINY = {"name": "tiny", "source": "test", "n": 2048, "d": 16, "k": 16,
         "mixture_components": 50, "seeder": "rejection",
         "backend": "device", "dtype": "float32", "reduced": [],
         "assumed": {}}
+TINY_SHARDED = dict(TINY, name="tiny-sharded", n=4101, backend="sharded")
 TINY_BATCH = {"kind": "reseed", "batch": 8, "warm_calls": 1,
               "cost_sample": 8, "trace_seconds": 1, "reference_seeds": 2}
 TINY_SERVED = {"kind": "closed_loop", "clients": 2, "max_batch": 2,
@@ -36,20 +40,25 @@ def make_copy(dst: Path) -> Path:
     shutil.copytree(CHIP, dst / "benchmarks" / "chip",
                     ignore=shutil.ignore_patterns("__pycache__"))
     root = dst / "benchmarks" / "chip"
-    (root / "configs" / "tiny.json").write_text(json.dumps(TINY))
+    spec = json.loads((dst / "BENCHMARK.json").read_text())
+    for config in (TINY, TINY_SHARDED):
+        name = config["name"]
+        (root / "configs" / f"{name}.json").write_text(json.dumps(config))
+        spec["configs"].append({"name": name, "source": "test",
+                                "file": f"benchmarks/chip/configs/{name}.json",
+                                "reduced": [], "why": "CPU tests"})
     (root / "traffic" / "tiny_served.json").write_text(
         json.dumps(TINY_SERVED))
     (root / "traffic" / "tiny_batch.json").write_text(json.dumps(TINY_BATCH))
-    spec = json.loads((dst / "BENCHMARK.json").read_text())
-    spec["configs"].append({"name": "tiny", "source": "test",
-                            "file": "benchmarks/chip/configs/tiny.json",
-                            "reduced": [], "why": "CPU tests"})
     limits = json.loads((root / "cells" / "kddcup-k500.reseed.json")
                         .read_text())
-    for mix in ("reseed", "tiny_batch", "tiny_served"):
-        name = f"tiny.{mix}"
-        spec["workloads"].append({"name": name, "config": "tiny",
-                                  "traffic": mix, "chips": 1,
+    for config, mix, chips in (("tiny", "reseed", 1),
+                               ("tiny", "tiny_batch", 1),
+                               ("tiny", "tiny_served", 1),
+                               ("tiny-sharded", "reseed", 4)):
+        name = f"{config}.{mix}"
+        spec["workloads"].append({"name": name, "config": config,
+                                  "traffic": mix, "chips": chips,
                                   "why": "CPU tests"})
         (root / "cells" / f"{name}.json").write_text(json.dumps(limits))
     (dst / "BENCHMARK.json").write_text(json.dumps(spec, indent=1))

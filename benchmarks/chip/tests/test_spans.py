@@ -8,6 +8,7 @@ import math
 from pathlib import Path
 from types import SimpleNamespace as NS
 
+import jax
 import pytest
 
 from _tiny import make_copy
@@ -104,7 +105,7 @@ def test_served_readers_on_a_tiny_served_run(tmp_path):
     work = reg.workload("tiny.tiny_served")
     driver = reg.module("traffic", "closed_loop").Driver(
         reg.config(work["config"]), reg.traffic(work["traffic"]), 5,
-        lambda *a: None)
+        lambda *a: None, jax.devices()[:1])
     driver.setup()
     try:
         win = driver.window(1.0)

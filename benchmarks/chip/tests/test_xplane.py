@@ -79,6 +79,33 @@ def test_roofline_share_of_the_hand_made_trace():
         roofline.peaks("TPU v9 imaginary")
 
 
+def _one_call_per_chip(chips: int, seconds: float):
+    """A trace in which each of `chips` chips runs one TREE call."""
+    ns = seconds * 1e9
+    host = NS(name="/host:CPU", lines=[NS(name="python", events=[
+        _ev("bench.window", 0, 2 * ns)])])
+    devices = [NS(name=f"/device:TPU:{i}", lines=[NS(
+        name="XLA Ops", events=[_ev(TREE, 100 + 7 * i, ns)])])
+        for i in range(chips)]
+    return NS(planes=[host, *devices])
+
+
+def test_roofline_share_is_per_chip():
+    from registry import Registry
+
+    shares = []
+    for chips in (1, 2, 4):
+        trace = xplane.reduce_profile(_one_call_per_chip(chips, 1e-6),
+                                      chips=chips)
+        assert trace.chips == chips
+        assert trace.kernel("tree_sep_update_pallas").calls == chips
+        run = NS(registry=Registry(), device_kind="TPU v5 lite", trace=trace)
+        shares.append(roofline.share(run, "tree_sep_update"))
+    nbytes = 4 * (2 * 16 * 4096 + 32 + 2 * 4096)
+    one = 100 * nbytes / roofline.peaks("TPU v5 lite")["hbm_bytes_per_s"] / 1e-6
+    assert shares == pytest.approx([one] * 3)
+
+
 LSH = ("%lsh_bucket_accept_pallas.7 = (f32[1,512]{1,0}, f32[1,512]{1,0}) "
        "custom-call(s32[16,512]{1,0} %a, s32[16,512]{1,0} %b, "
        "f32[512,74]{1,0} %q, s32[16,512]{1,0} %c, s32[16,512]{1,0} %d, "

@@ -30,9 +30,9 @@ import threading
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
+import reference
 from checks import Answer, derive_seed
 from data import point_set
 
@@ -41,9 +41,11 @@ ANSWER_GRACE_S = 60.0        # how long past the close an answer may take
 
 
 class Driver:
-    def __init__(self, config: dict, traffic: dict, seed: int, log):
+    def __init__(self, config: dict, traffic: dict, seed: int, log,
+                 devices: list):
         self.config, self.traffic, self.seed, self.log = (
             config, traffic, seed, log)
+        self.devices = devices
         self.server = None
         self.conns: list = []
         self._dev = (None, None)
@@ -190,10 +192,17 @@ class Driver:
     def host_points(self, set_key) -> np.ndarray:
         return self.request_points(*set_key).astype(np.float64)
 
-    def points_dev(self, set_key):
+    def points_dev(self, set_key) -> reference.Rows:
+        """The reference's rows of one set, over the cell's chips; the
+        last set asked for is kept until `free_points_dev`."""
         if self._dev[0] != set_key:
-            self._dev = (set_key, jnp.asarray(self.request_points(*set_key)))
+            self._dev = (None, None)      # the last set leaves first
+            self._dev = (set_key, reference.place(
+                self.request_points(*set_key), self.devices))
         return self._dev[1]
+
+    def free_points_dev(self) -> None:
+        self._dev = (None, None)
 
     def cost_sample(self, answers: list) -> list:
         came = sorted((a for a in answers if a.indices is not None),

@@ -17,9 +17,9 @@ from __future__ import annotations
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
+import reference
 from checks import Answer, derive_seed
 from data import point_set
 
@@ -27,9 +27,11 @@ WINDOW_STREAM, WARM_STREAM, SAMPLE_STREAM = 1, 0, 5
 
 
 class Driver:
-    def __init__(self, config: dict, traffic: dict, seed: int, log):
+    def __init__(self, config: dict, traffic: dict, seed: int, log,
+                 devices: list):
         self.config, self.traffic, self.seed, self.log = (
             config, traffic, seed, log)
+        self.devices = devices
         self.batch = int(traffic["batch"])
         self.label = f"{config['seeder']}/{config['backend']}"
         self.points = None
@@ -94,10 +96,14 @@ class Driver:
 
     def kernel_widths(self) -> dict:
         """The kernel check's widths: k, the tree heights and LSH tables of
-        the prepared arrays the window swept, the largest candidate block."""
+        the prepared arrays the window swept, the largest candidate block.
+        The device backend prepares a `DeviceSeedingData`; the sharded one
+        a (`DeviceSeedingData`, n) pair."""
         from repro.core import BatchSchedule
 
         art = self.prepared.artifacts
+        if isinstance(art, tuple):
+            art = art[0]
         return {"k": self.config["k"], "h": art.codes_lo.shape[1],
                 "l": art.keys_lo.shape[0], "b": BatchSchedule().buckets()[-1]}
 
@@ -120,10 +126,15 @@ class Driver:
             self.points = point_set(self.config, self.seed)
         return self.points
 
-    def points_dev(self, set_key):
+    def points_dev(self, set_key) -> reference.Rows:
+        """The reference's rows, over the cell's chips, kept until
+        `free_points_dev`."""
         if self._dev is None:
-            self._dev = jnp.asarray(self.host_points(set_key), jnp.float32)
+            self._dev = reference.place(self.rows(set_key), self.devices)
         return self._dev
+
+    def free_points_dev(self) -> None:
+        self._dev = None
 
     def cost_sample(self, answers: list) -> list:
         came = [a for a in answers if a.indices is not None]
