@@ -349,11 +349,11 @@ def phase_sharded(clock: CompileClock):
           f"backends in {time.perf_counter() - t0:.1f}s", flush=True)
 
     data, _ = preps["sharded"].artifacts
-    n_pad = data.points.shape[0]
+    n_pad = data.points.shape[1]            # coordinate planes (d8, n_pad)
     _require(points_axis(mesh, n_pad) is not None,
              f"points_axis is None for n_pad={n_pad}: points replicated")
     shards = data.points.addressable_shards
-    rows = sorted(sh.data.shape[0] for sh in shards)
+    rows = sorted(sh.data.shape[1] for sh in shards)
     _require(len({sh.device for sh in shards}) == 4
              and rows == [n_pad // 4] * 4,
              f"points not split four ways: shard rows {rows}, n_pad {n_pad}")

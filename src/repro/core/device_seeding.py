@@ -9,7 +9,7 @@ The pointer-machine data structures become arrays (DESIGN.md §3):
     per-tile weight sums.  The kernel wants heights padded to a multiple
     of 8 and points to the tile; the codes never change during a seeding,
     so they are padded and split into per-tree planes once, before the
-    k-center loop (`_make_open_center`), and no sweep re-pads them;
+    k-center loop (`_make_sweep`), and no sweep re-pads them;
   - MULTITREESAMPLE is the two-level `TiledSampleTree` descent: a coarse
     flat heap over the T = n/tile tile sums plus one vectorised intra-tile
     cumsum.  After each opened center the coarse heap is fixed *in place*
@@ -61,7 +61,13 @@ from repro.core.batch_schedule import BatchSchedule, shape_bucket
 from repro.core.lsh import MonotoneLSH
 from repro.core.sample_tree import TiledSampleTree
 from repro.core.tracing import count_trace, span
-from repro.core.tree_embedding import build_multitree, compute_max_dist
+from repro.core.tree_embedding import (
+    GridTrees,
+    build_multitree,
+    compute_max_dist,
+    grid_trees,
+    set_bounds,
+)
 from repro.kernels.ops import (
     lsh_bucket_accept,
     pad_tree_codes,
@@ -77,7 +83,9 @@ __all__ = [
     "device_kmeans_parallel_rounds",
     "prepare_embedding",
     "prepare_rejection",
+    "rejection_encoder",
     "DeviceSeedingData",
+    "RejectionEncoder",
     "StackedLane",
     "stacked_rejection_sampling",
     "stacked_fast_kmeanspp",
@@ -123,41 +131,61 @@ def _pad_axis(a: jax.Array, axis: int, n_pad: int) -> jax.Array:
     return jnp.pad(a, widths)
 
 
-def _make_open_center(codes_lo, codes_hi, *, scale, num_levels, tile,
-                      interpret):
-    """Per-center fused sweep over all trees; the last tree's kernel emits
-    the per-tile weight sums the coarse heap update consumes (one pass over
-    the weight vector, not over the points).
+def _make_sweep(codes_lo, codes_hi, *, scale, num_levels, tile, interpret):
+    """MULTITREEOPEN over all trees: ``sweep(weights, col_lo, col_hi)``
+    for an opened center's code columns, and ``columns(x)``, point x's
+    columns.  The last tree's kernel emits the per-tile weight sums the
+    coarse heap update consumes (one pass over the weight vector, not
+    over the points).
 
-    Call it outside the per-center loop: it pads and splits the (T, H-1, n)
-    codes here, once (`ops.pad_tree_codes`), and the returned sweep closes
+    Call it outside the per-center loop: it pads and splits the (T, H-1,
+    n) codes here, once (`ops.pad_tree_codes`), and both functions close
     over the T per-tree (H8, n_pad) planes, so the loop body slices and
-    pads no code plane.  The opened center's column is read from the first
-    H-1 rows only: the wrapper pads it with the center sentinel -2, where
-    the planes carry the point sentinel -1.  Read from all H8 rows it would
-    carry -1 there, match every point on the pad rows and raise each
-    point's `sep` by the pad height.
+    pads no code plane (and reads no column of the unpadded codes, which
+    on a TPU can make XLA hold them in a second layout).  A column is
+    read from the first H-1 rows only: the wrapper pads it with the
+    center sentinel -2, where the planes carry the point sentinel -1.
+    Read from all H8 rows it would carry -1 there, match every point on
+    the pad rows and raise each point's `sep` by the pad height.  The
+    sharded programs call this on each shard's codes.
     """
     h = codes_lo.shape[1]
     planes = list(zip(pad_tree_codes(codes_lo, block_n=tile),
                       pad_tree_codes(codes_hi, block_n=tile)))
 
-    def column(plane, x):
-        return jax.lax.dynamic_slice(plane, (0, x), (h, 1))[:, 0]
+    def columns(x):
+        def column(plane):
+            return jax.lax.dynamic_slice(plane, (0, x), (h, 1))[:, 0]
 
-    def open_center(weights, x):
-        for lo, hi in planes[:-1]:
+        return ([column(lo) for lo, _ in planes],
+                [column(hi) for _, hi in planes])
+
+    def sweep(weights, col_lo, col_hi):
+        for ti, (lo, hi) in enumerate(planes[:-1]):
             weights = tree_sep_update(
-                lo, hi, column(lo, x), column(hi, x), weights,
+                lo, hi, col_lo[ti], col_hi[ti], weights,
                 scale=scale, num_levels=num_levels, block_n=tile,
                 interpret=interpret,
             )
         lo, hi = planes[-1]
         return tree_sep_update_tiles(
-            lo, hi, column(lo, x), column(hi, x), weights,
+            lo, hi, col_lo[-1], col_hi[-1], weights,
             scale=scale, num_levels=num_levels, block_n=tile,
             interpret=interpret,
         )
+
+    return sweep, columns
+
+
+def _make_open_center(codes_lo, codes_hi, *, scale, num_levels, tile,
+                      interpret):
+    """`_make_sweep` for a center given by its row index."""
+    sweep, columns = _make_sweep(codes_lo, codes_hi, scale=scale,
+                                 num_levels=num_levels, tile=tile,
+                                 interpret=interpret)
+
+    def open_center(weights, x):
+        return sweep(weights, *columns(x))
 
     return open_center
 
@@ -266,6 +294,82 @@ class DeviceSeedingData:
     m_init: float            # M = 16 d MaxDist^2
 
 
+@dataclasses.dataclass(frozen=True)
+class RejectionEncoder:
+    """What Algorithm 4's host prepare fixes from the whole point set: the
+    trees' draws over its bounds and the LSH family.  `encode` turns any
+    block of rows into its code and key planes on its own, bit for bit
+    the columns the whole set's encoding would give those rows, so a set
+    can be encoded block by block, on several threads, and never needs a
+    temporary the size of the set."""
+
+    trees: GridTrees
+    lsh: MonotoneLSH
+
+    @property
+    def meta(self) -> dict:
+        """The program's statics: the tree-distance scale, H, M."""
+        d, md = len(self.trees.origin), self.trees.max_dist
+        return {"scale": 2.0 * np.sqrt(d) * md,
+                "num_levels": self.trees.num_levels,
+                "m_init": 16.0 * d * md ** 2}
+
+    def codes(self, rows: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+        """(T, H-1, b) int32 lo/hi code planes of `rows` (float64), the
+        trivial root level dropped."""
+        t = len(self.trees.shifts)
+        lo = np.empty((t, self.trees.num_levels - 1, len(rows)), np.int32)
+        hi = np.empty_like(lo)
+        for ti in range(t):
+            lo[ti], hi[ti] = split_codes_u64(
+                self.trees.tree_codes(rows, ti)[1:])
+        return lo, hi
+
+    def keys(self, rows: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+        """(L, b) int32 lo/hi LSH bucket-key planes of `rows`."""
+        klo, khi = split_codes_u64(self.lsh.hash_keys(rows))
+        return klo.T, khi.T
+
+
+def rejection_encoder(
+    points: np.ndarray,
+    *,
+    seed: int = 0,
+    resolution: Optional[float] = None,
+    lsh_r: Optional[float] = None,
+    num_tables: int = 15,
+    hashes_per_table: int = 1,
+    max_dist: Optional[float] = None,
+    map_fn=map,
+) -> RejectionEncoder:
+    """The whole-set half of `prepare_rejection`: the diameter bound and
+    origin in one pass over row blocks (`set_bounds`, whose blocks
+    `map_fn` maps), the LSH width, and every random draw, in the order
+    `prepare_rejection` has always made them."""
+    pts = np.asarray(points, dtype=np.float64)
+    rng = np.random.default_rng(seed)
+    tree_seed = int(rng.integers(2 ** 31))
+    if max_dist is None:
+        max_dist, origin = set_bounds(pts, map_fn=map_fn)
+    else:
+        max_dist, origin = float(max_dist), pts.min(axis=0)
+    trees = grid_trees(max_dist, origin, seed=tree_seed,
+                       resolution=resolution)
+    if lsh_r is None:
+        from repro.core.seeding import _estimate_scale
+
+        lsh_r = 10.0 * (resolution or _estimate_scale(pts, rng))
+    lsh = MonotoneLSH(
+        pts.shape[1],
+        r=lsh_r,
+        num_tables=num_tables,
+        hashes_per_table=hashes_per_table,
+        seed=int(rng.integers(2 ** 31)),
+        capacity=16,
+    )
+    return RejectionEncoder(trees=trees, lsh=lsh)
+
+
 def prepare_rejection(
     points: np.ndarray,
     *,
@@ -284,35 +388,27 @@ def prepare_rejection(
     CPU structure's.  The paper's LSH stores only *opened centers*; since
     every center is an input point, precomputing all n keys host-side lets
     the device program insert a center by copying one precomputed column.
+    The set is encoded as one block, on this thread (`RejectionEncoder`;
+    the sharded backend encodes its shards block by block).
     """
     pts = np.asarray(points, dtype=np.float64)
-    n, d = pts.shape
-    rng = np.random.default_rng(seed)
     with span("repro.prepare.embed"):
-        lo, hi, meta = prepare_embedding(
-            pts, seed=int(rng.integers(2 ** 31)), resolution=resolution,
-            max_dist=max_dist,
-        )
+        enc = rejection_encoder(
+            pts, seed=seed, resolution=resolution, lsh_r=lsh_r,
+            num_tables=num_tables, hashes_per_table=hashes_per_table,
+            max_dist=max_dist)
+        lo, hi = enc.codes(pts)
+        lo, hi = jnp.asarray(lo), jnp.asarray(hi)
     with span("repro.prepare.lsh"):
-        if lsh_r is None:
-            from repro.core.seeding import _estimate_scale
-
-            lsh_r = 10.0 * (resolution or _estimate_scale(pts, rng))
-        lsh = MonotoneLSH(
-            d,
-            r=lsh_r,
-            num_tables=num_tables,
-            hashes_per_table=hashes_per_table,
-            seed=int(rng.integers(2 ** 31)),
-            capacity=16,
-        )
-        klo, khi = split_codes_u64(lsh.hash_keys(pts))  # (n, L) planes
+        klo, khi = enc.keys(pts)
+        klo, khi = jnp.asarray(klo), jnp.asarray(khi)
+    meta = enc.meta
     return DeviceSeedingData(
         codes_lo=lo,
         codes_hi=hi,
         points=jnp.asarray(pts, jnp.float32),
-        keys_lo=jnp.asarray(klo.T),                 # (L, n)
-        keys_hi=jnp.asarray(khi.T),
+        keys_lo=klo,                                # (L, n)
+        keys_hi=khi,
         scale=meta["scale"],
         num_levels=meta["num_levels"],
         m_init=meta["m_init"],

@@ -47,6 +47,7 @@ import dataclasses
 import functools
 import hashlib
 import math
+import os
 import threading
 import time
 from typing import Any, Optional, Sequence
@@ -103,13 +104,32 @@ SOLVE_SPAN = "repro.plan.solve"
 
 _FULL_HASH_BYTES = 1 << 22          # full-hash threshold for device arrays
 _SAMPLE_ROWS = 4096
+_CHUNK_HASH_BYTES = 1 << 26         # host arrays above: 64 MiB chunks
+
+
+def _chunk_digests(points: np.ndarray) -> list:
+    """blake2b digests of consecutive row chunks of about 64 MiB, hashed
+    side by side on a thread pool (hashlib releases the GIL)."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    rows = max(1, _CHUNK_HASH_BYTES * points.shape[0] // points.nbytes)
+    starts = range(0, points.shape[0], rows)
+
+    def digest(lo):
+        chunk = np.ascontiguousarray(points[lo:lo + rows])
+        return hashlib.blake2b(chunk, digest_size=16).digest()
+
+    with ThreadPoolExecutor(min(len(starts), os.cpu_count() or 1)) as pool:
+        return list(pool.map(digest, starts))
 
 
 def data_fingerprint(points) -> str:
     """Content fingerprint keying the prepare cache.
 
     Host (numpy) arrays hash their full bytes — blake2b streams at GB/s,
-    negligible next to the O(nd log Δ) prepare work the cache avoids.
+    negligible next to the O(nd log Δ) prepare work the cache avoids;
+    above 64 MiB, in row chunks on several threads, their digests hashed
+    in order.
     Device (jax) arrays above 4 MiB avoid a full transfer: a strided row
     sample crosses to the host, plus per-column and total sums computed
     on-device — so any row mutation (even off the sample stride) changes
@@ -119,7 +139,10 @@ def data_fingerprint(points) -> str:
     shape = tuple(int(s) for s in points.shape)
     h.update(repr((shape, str(points.dtype))).encode())
     nbytes = int(np.prod(shape, dtype=np.int64)) * points.dtype.itemsize
-    if isinstance(points, np.ndarray) or nbytes <= _FULL_HASH_BYTES \
+    if isinstance(points, np.ndarray) and nbytes > _CHUNK_HASH_BYTES:
+        for digest in _chunk_digests(points):
+            h.update(digest)
+    elif isinstance(points, np.ndarray) or nbytes <= _FULL_HASH_BYTES \
             or not shape:
         h.update(np.ascontiguousarray(points).tobytes())
     else:
@@ -376,7 +399,7 @@ class PreparedData:
     artifacts: Any                    # BackendImpl.prepare output (or None)
     rng_state: dict                   # np.Generator state after prep draws
     prepare_seconds: float
-    points_dev: Any = None            # lazy device copy for gather/cost
+    points_dev: Any = None            # lazy device rows for gather/cost
     # Streaming (ISSUE 10): a mutable `repro.core.streaming.StreamState`
     # makes this handle extendable/retirable in place.  Because mutation
     # invalidates the content fingerprint above, the prepare cache re-keys
@@ -685,7 +708,8 @@ class ClusterPlan:
             prepare_seconds=time.perf_counter() - t0,
         )
         if isinstance(points, jax.Array) and str(points.dtype) == \
-                self._ctx.dtype and points.ndim == 2:
+                self._ctx.dtype and points.ndim == 2 and \
+                self._ctx.backend != "sharded":
             prep.points_dev = points       # reuse: no host round-trip
         return prep
 
@@ -724,10 +748,19 @@ class ClusterPlan:
             )
         return active
 
-    def _points_device(self, prep: PreparedData) -> jax.Array:
+    def _points_device(self, prep: PreparedData):
+        """The rows centers are gathered from and costs taken over: one
+        device array, or on the sharded backend a `PlacedRows` split over
+        the mesh (no device ever holds the whole set)."""
         if prep.points_dev is None:
-            prep.points_dev = jnp.asarray(prep.pts,
-                                          jnp.dtype(self._ctx.dtype))
+            if self._ctx.backend == "sharded":
+                from repro.core.sharded_seeding import place_rows
+
+                prep.points_dev = place_rows(prep.pts, self._ctx.mesh,
+                                             dtype=self._ctx.dtype)
+            else:
+                prep.points_dev = jnp.asarray(prep.pts,
+                                              jnp.dtype(self._ctx.dtype))
         return prep.points_dev
 
     # -- execute stage ------------------------------------------------------
@@ -837,7 +870,9 @@ class ClusterPlan:
                 t0: float) -> FitResult:
         idx = jnp.asarray(idx_raw, jnp.int32)
         pts_dev = self._points_device(prep)
-        centers = jnp.take(pts_dev, idx, axis=0)
+        sharded = self._ctx.backend == "sharded"
+        centers = (pts_dev.take(idx) if sharded
+                   else jnp.take(pts_dev, idx, axis=0))
         if self.cluster.lloyd_iters > 0:
             refinement = lloyd(prep.pts,
                                prep.pts[np.asarray(idx, dtype=np.int64)],
@@ -846,6 +881,8 @@ class ClusterPlan:
                                   jnp.dtype(self._ctx.dtype))
             cost = jnp.asarray(refinement.cost, jnp.float32)
             extras = dict(extras, lloyd_iterations=refinement.iterations)
+        elif sharded:
+            cost = pts_dev.cost(centers)
         else:
             cost = _cost_program(pts_dev, centers)
         return FitResult(

@@ -42,32 +42,34 @@ in lockstep.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import time
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.batch_schedule import BatchSchedule
 from repro.core.device_seeding import (
     _FAR,
+    DeviceSeedingData,
+    _make_sweep,
     _pad_axis,
     prepare_embedding,
-    prepare_rejection,
+    rejection_encoder,
     resolve_schedule,
 )
+from repro.core.plan import _pairwise_d2
+from repro.core.preprocess import host_block_map
 from repro.core.sample_tree import TiledSampleTree
-from repro.core.tracing import TRACE_COUNTS
+from repro.core.tracing import TRACE_COUNTS, span
+from repro.core.tree_embedding import hash_level_planes
 from repro.distributed.sharding import _mesh_size, points_axis
-from repro.kernels.ops import (
-    lsh_bucket_accept,
-    pairwise_argmin,
-    tree_sep_update,
-    tree_sep_update_tiles,
-)
+from repro.kernels.ops import lsh_bucket_accept, pairwise_argmin
 from repro.launch.mesh import make_seeding_mesh
 
 __all__ = [
@@ -79,6 +81,8 @@ __all__ = [
     "sharded_kmeans_parallel_seeder",
     "SHARDED_SEEDERS",
     "TRACE_COUNTS",
+    "PlacedRows",
+    "place_rows",
     "program_cache_info",
 ]
 
@@ -143,29 +147,15 @@ def _broadcast_from_owner(x_glob, n_loc, axis, *columns):
     return out
 
 
-def _make_local_open(codes_lo_loc, codes_hi_loc, *, scale, num_levels, tile,
-                     interpret):
-    """Sharded MULTITREEOPEN: each device sweeps only its own points; the
-    last tree's sweep also returns the local tile sums for the sub-heap
-    refresh."""
-    t = codes_lo_loc.shape[0]
-
-    def open_center(weights, col_lo, col_hi):
-        for ti in range(t - 1):
-            weights = tree_sep_update(
-                codes_lo_loc[ti], codes_hi_loc[ti],
-                col_lo[ti], col_hi[ti], weights,
-                scale=scale, num_levels=num_levels, block_n=tile,
-                interpret=interpret,
-            )
-        return tree_sep_update_tiles(
-            codes_lo_loc[t - 1], codes_hi_loc[t - 1],
-            col_lo[t - 1], col_hi[t - 1], weights,
-            scale=scale, num_levels=num_levels, block_n=tile,
-            interpret=interpret,
-        )
-
-    return open_center
+def _plane_rows(planes, idx, mode=None):
+    """Rows `idx` of a point set held as coordinate planes (d8, n): the
+    (len(idx), d8) coordinates.  Taken through the (d8 / 8, 8, n) view of
+    the planes, a gather XLA runs in the planes' own layout; from the 2-D
+    view it first copies every plane into another (on a TPU, a second
+    copy of the set, larger than the first)."""
+    g, n = planes.shape[0] // 8, planes.shape[1]
+    got = jnp.take(planes.reshape(g, 8, n), idx, axis=2, mode=mode)
+    return got.reshape(g * 8, -1).T
 
 
 def _init_weights(n_loc, n_real, m_init, axis):
@@ -193,9 +183,13 @@ def _fastkmeanspp_program(mesh, t, h, n_pad, k, scale, num_levels, m_init,
     def program(clo, chi, bits):
         TRACE_COUNTS["fastkmeans++"] += 1     # trace-time only
         key = jax.random.wrap_key_data(bits)
-        open_center = _make_local_open(clo, chi, scale=scale,
-                                       num_levels=num_levels, tile=tile,
-                                       interpret=interpret)
+        # Sharded MULTITREEOPEN: each device sweeps only its own points,
+        # for the opened center's code columns (read on its owner shard
+        # and broadcast); the shard's code planes are padded here, once
+        # per seeding.
+        sweep, columns = _make_sweep(clo, chi, scale=scale,
+                                     num_levels=num_levels, tile=tile,
+                                     interpret=interpret)
         sample = _shard_sampler(ts_loc, axis)
 
         def body(i, state):
@@ -207,9 +201,10 @@ def _fastkmeanspp_program(mesh, t, h, n_pad, k, scale, num_levels, m_init,
             ).astype(jnp.int32)
             col_lo, col_hi = _broadcast_from_owner(
                 x, n_loc, axis,
-                lambda xl: clo[:, :, xl], lambda xl: chi[:, :, xl],
+                lambda xl: jnp.stack(columns(xl)[0]),
+                lambda xl: jnp.stack(columns(xl)[1]),
             )
-            w, tsums = open_center(w, col_lo, col_hi)
+            w, tsums = sweep(w, col_lo, col_hi)
             coarse = ts_loc.refresh(coarse, tsums)
             chosen = chosen.at[i].set(x)
             return w, coarse, chosen, key
@@ -266,9 +261,13 @@ def _rejection_program(mesh, t, h, n_pad, l, d, k, scale, num_levels, m_init,
     def program(clo, chi, pts_loc, klo, khi, bits):
         TRACE_COUNTS["rejection"] += 1        # trace-time only
         key = jax.random.wrap_key_data(bits)
-        open_center = _make_local_open(clo, chi, scale=scale,
-                                       num_levels=num_levels, tile=tile,
-                                       interpret=interpret)
+        # Sharded MULTITREEOPEN: each device sweeps only its own points,
+        # for the opened center's code columns (read on its owner shard
+        # and broadcast); the shard's code planes are padded here, once
+        # per seeding.
+        sweep, columns = _make_sweep(clo, chi, scale=scale,
+                                     num_levels=num_levels, tile=tile,
+                                     interpret=interpret)
         sample = _shard_sampler(ts_loc, axis)
 
         def body(i, state):
@@ -302,7 +301,8 @@ def _rejection_program(mesh, t, h, n_pad, l, d, k, scale, num_levels, m_init,
                         # one int32 (2L, B) payload — the round's collective
                         # latency floor.
                         fpay = jnp.concatenate(
-                            [pts_loc[loc], w[loc][:, None]], axis=1
+                            [_plane_rows(pts_loc, loc), w[loc][:, None]],
+                            axis=1
                         )
                         fpay = jax.lax.psum(
                             jnp.where(mine[:, None], fpay, 0.0), axis
@@ -354,11 +354,13 @@ def _rejection_program(mesh, t, h, n_pad, l, d, k, scale, num_levels, m_init,
 
             col_lo, col_hi, x_pt, xk_lo, xk_hi = _broadcast_from_owner(
                 x, n_loc, axis,
-                lambda xl: clo[:, :, xl], lambda xl: chi[:, :, xl],
-                lambda xl: pts_loc[xl], lambda xl: klo[:, xl],
+                lambda xl: jnp.stack(columns(xl)[0]),
+                lambda xl: jnp.stack(columns(xl)[1]),
+                lambda xl: _plane_rows(pts_loc, xl[None])[0],
+                lambda xl: klo[:, xl],
                 lambda xl: khi[:, xl],
             )
-            w, tsums = open_center(w, col_lo, col_hi)
+            w, tsums = sweep(w, col_lo, col_hi)
             coarse = ts_loc.refresh(coarse, tsums)
             chosen = chosen.at[i].set(x)
             ctr_pts = ctr_pts.at[i].set(x_pt)
@@ -388,7 +390,7 @@ def _rejection_program(mesh, t, h, n_pad, l, d, k, scale, num_levels, m_init,
         program, mesh=mesh,
         in_specs=(
             P(None, None, axis), P(None, None, axis),
-            P(axis, None), P(None, axis), P(None, axis), P(),
+            P(None, axis), P(None, axis), P(None, axis), P(),
         ),
         out_specs=(P(), P()),
         check_vma=False,
@@ -399,7 +401,7 @@ def _rejection_program(mesh, t, h, n_pad, l, d, k, scale, num_levels, m_init,
 def sharded_rejection_sampling(
     codes_lo: jax.Array,     # (T, H-1, n_pad) int32
     codes_hi: jax.Array,
-    points: jax.Array,       # (n_pad, d) f32
+    points: jax.Array,       # (d8, n_pad) f32 coordinate planes
     keys_lo: jax.Array,      # (L, n_pad) int32
     keys_hi: jax.Array,
     k: int,
@@ -421,14 +423,17 @@ def sharded_rejection_sampling(
     Candidate batches are drawn shard-then-descend; each candidate's
     coordinates, bucket keys and current weight cross chips with one masked
     psum, after which the (small, replicated) opened-center acceptance sweep
-    runs everywhere so the rejection `while_loop` stays in lockstep.  The
-    batch size follows the adaptive `schedule` exactly as in
-    `device_rejection_sampling` (see that docstring).
+    runs everywhere so the rejection `while_loop` stays in lockstep.
+    `points` are the coordinates as planes, as `prepare_rejection_sharded`
+    lays them out: d8 = d rounded up to a multiple of 8, the rows beyond d
+    zero, which add nothing to a distance.  The batch size follows the
+    adaptive `schedule` exactly as in `device_rejection_sampling` (see
+    that docstring).
     Returns ``(chosen (k,), trials (k,))`` as in the single-device program.
     """
     t, h, n_pad = codes_lo.shape
     l = keys_lo.shape[0]
-    d = points.shape[1]
+    d = points.shape[0]
     schedule = schedule if schedule is not None else BatchSchedule()
     fn = _rejection_program(mesh, t, h, n_pad, l, d, k, scale, num_levels,
                             m_init, n_real, c, schedule, max_rounds, tile,
@@ -519,6 +524,281 @@ def sharded_kmeans_parallel_rounds(
 
 
 # ---------------------------------------------------------------------------
+# Host prepare, shard by shard: the whole-set statistics in one bounded pass,
+# then each shard's codes, keys and coordinates built on the host in row
+# blocks and put on its own chips.  No whole-set temporary, no whole copy
+# on any one device.
+# ---------------------------------------------------------------------------
+
+#: Rows one host task encodes: its float64 temporaries are a few MB, not
+#: the set's.  A block starts on a multiple of the tile (shards start on
+#: one), so its LSH projections take the BLAS path the whole set's take.
+PREPARE_BLOCK_ROWS = 8192
+
+
+def _shard_ranges(mesh, n_pad: int) -> dict:
+    """(first, end) column of each device's shard of the points axis ->
+    the devices that hold it."""
+    sharding = NamedSharding(mesh, P(points_axis(mesh, n_pad)))
+    ranges: dict = {}
+    for dev, index in sharding.addressable_devices_indices_map(
+            (n_pad,)).items():
+        lo, hi, _ = index[0].indices(n_pad)
+        ranges.setdefault((lo, hi), []).append(dev)
+    return dict(sorted(ranges.items()))
+
+
+def _assemble(mesh, spec, shape, placed: dict) -> jax.Array:
+    """One global array from per-device shards already on their devices."""
+    sharding = NamedSharding(mesh, spec)
+    devices = sharding.addressable_devices_indices_map(shape)
+    return jax.make_array_from_single_device_arrays(
+        shape, sharding, [placed[dev] for dev in devices])
+
+
+def _encode_shard(enc, pts, lo, hi, map_fn) -> dict:
+    """The host's part of rows [lo, hi): every tree's deepest-level cells
+    (T, hi - lo, d), which the shard's device hashes into codes, and the
+    LSH key and coordinate planes; zero past the set's last row."""
+    n, d = pts.shape
+    width, real = hi - lo, max(0, min(hi, n) - lo)
+    trees = len(enc.trees.shifts)
+    out = {
+        "cells": np.zeros((trees, width, d), enc.trees.cell_dtype),
+        "keys_lo": np.zeros((enc.lsh.L, width), np.int32),
+        "keys_hi": np.zeros((enc.lsh.L, width), np.int32),
+        "points": np.zeros((-(-d // 8) * 8, width), np.float32),
+    }
+
+    def block(a):
+        rows = pts[lo + a:lo + min(a + PREPARE_BLOCK_ROWS, real)]
+        cols = slice(a, a + len(rows))
+        with span("repro.prepare.embed"):
+            for t in range(trees):
+                out["cells"][t, cols] = enc.trees.cells(rows, t)
+        with span("repro.prepare.lsh"):
+            out["keys_lo"][:, cols], out["keys_hi"][:, cols] = enc.keys(rows)
+        out["points"][:d, cols] = rows.T
+
+    list(map_fn(block, range(0, real, PREPARE_BLOCK_ROWS)))
+    return out
+
+
+def prepare_rejection_sharded(pts, *, mesh, tile, seed, resolution=None,
+                              lsh_r=None, num_tables=15,
+                              hashes_per_table=1) -> DeviceSeedingData:
+    """`device_seeding.prepare_rejection` for the mesh, shard by shard.
+
+    The whole-set statistics (diameter bound, origin, LSH width) and every
+    random draw come first, as `prepare_rejection` makes them
+    (`rejection_encoder`).  Then each shard of the points axis is encoded
+    on the host in blocks of `PREPARE_BLOCK_ROWS` rows, over a thread pool
+    (one block: this thread), and put on its own devices; the global
+    arrays are assembled from the placed shards.  The host does the float
+    work (each tree's deepest-level cells, the LSH keys); the trees'
+    integer hashes, most of the work, run on the devices, each on its own
+    shard's cells, in one program over the mesh
+    (`tree_embedding.hash_level_planes`).  Codes and keys are bit for bit
+    the whole-set prepare's, padded with zero columns to `n_pad` (a
+    multiple of devices x `tile`); the coordinates are float32 planes (d8,
+    n_pad), d rounded up to a multiple of 8 with zero rows.
+
+    Spans: ``repro.prepare.shard`` (``shard=i``) around each shard's host
+    build and placement, ``repro.prepare.place`` inside it around the
+    transfer, ``repro.prepare.embed`` (cells, and the device hash) and
+    ``repro.prepare.lsh`` (keys) per block, summed over threads.
+    """
+    pts = np.asarray(pts, dtype=np.float64)
+    n, d = pts.shape
+    n_pad = _padded_for_mesh(n, mesh, tile)
+    axis = points_axis(mesh, n_pad)
+    with host_block_map(-(-n // PREPARE_BLOCK_ROWS)) as map_fn:
+        with span("repro.prepare.embed"):
+            enc = rejection_encoder(
+                pts, seed=seed, resolution=resolution, lsh_r=lsh_r,
+                num_tables=num_tables, hashes_per_table=hashes_per_table,
+                map_fn=map_fn)
+        placed: dict = {}
+        for i, ((lo, hi), devices) in enumerate(
+                _shard_ranges(mesh, n_pad).items()):
+            with span("repro.prepare.shard", shard=i):
+                host = _encode_shard(enc, pts, lo, hi, map_fn)
+                with span("repro.prepare.place", shard=i):
+                    for dev in devices:
+                        for name, a in jax.block_until_ready(
+                                jax.device_put(host, dev)).items():
+                            placed.setdefault(name, {})[dev] = a
+                del host
+    trees = len(enc.trees.shifts)
+    cells = _assemble(mesh, P(None, axis, None), (trees, n_pad, d),
+                      placed.pop("cells"))
+    with span("repro.prepare.embed"):
+        hash_fn = _hash_program(mesh, n_pad, n, enc.trees.num_levels,
+                                HASH_CHUNK_ROWS)
+        with jax.enable_x64(True):
+            codes_lo, codes_hi = jax.block_until_ready(
+                hash_fn(cells, np.stack(enc.trees.mults)[:, None, :]))
+    del cells
+    keys = (enc.lsh.L, n_pad)
+    meta = enc.meta
+    return DeviceSeedingData(
+        codes_lo=codes_lo,
+        codes_hi=codes_hi,
+        points=_assemble(mesh, P(None, axis), (-(-d // 8) * 8, n_pad),
+                         placed["points"]),
+        keys_lo=_assemble(mesh, P(None, axis), keys, placed["keys_lo"]),
+        keys_hi=_assemble(mesh, P(None, axis), keys, placed["keys_hi"]),
+        scale=meta["scale"],
+        num_levels=meta["num_levels"],
+        m_init=meta["m_init"],
+    )
+
+
+#: Rows of a shard one step of the device hash takes: its uint64
+#: temporaries are a chunk's, not the shard's.
+HASH_CHUNK_ROWS = 1 << 20
+
+
+@functools.lru_cache(maxsize=None)
+def _hash_program(mesh, n_pad, n_real, num_levels, chunk):
+    """The trees' hash over the mesh: each device hashes its own shard of
+    the (T, n_pad, d) cells into (T, H-1, n_pad) lo/hi code planes, zero
+    past the set's last row, `chunk` rows at a time.  One program for
+    every shard, so one compile (call it with x64 on)."""
+    axis = points_axis(mesh, n_pad)
+    n_loc = n_pad // _mesh_size(mesh, axis)
+    chunk = min(chunk, n_loc)
+
+    def program(cells_loc, mults):
+        first = jax.lax.axis_index(axis) * n_loc
+
+        def step(i, planes):
+            # The last chunk ends where the shard does; it may overlap the
+            # one before it, whose codes it writes again unchanged.
+            at = jnp.minimum(i * chunk, n_loc - chunk)
+            got = hash_level_planes(
+                jax.lax.dynamic_slice_in_dim(cells_loc, at, chunk, axis=1),
+                mults, n_real - first - at, num_levels)
+            return tuple(jax.lax.dynamic_update_slice_in_dim(p, g, at, axis=2)
+                         for p, g in zip(planes, got))
+
+        zero = jnp.zeros((cells_loc.shape[0], num_levels - 1, n_loc),
+                         jnp.int32)
+        return jax.lax.fori_loop(0, -(-n_loc // chunk), step, (zero, zero))
+
+    codes = P(None, None, axis)
+    return jax.jit(jax.shard_map(
+        program, mesh=mesh, in_specs=(P(None, axis, None), P()),
+        out_specs=(codes, codes), check_vma=False))
+
+
+# ---------------------------------------------------------------------------
+# A set's float32 rows over the mesh, for the plan's finish: each seeding's
+# centers gathered from them and its cost reduced inside the mesh.
+# ---------------------------------------------------------------------------
+
+#: Rows per device per block of the reported cost: a block's (rows, k)
+#: distance matrix is its only temporary.
+COST_BLOCK_ROWS = 32768
+
+
+@dataclasses.dataclass(frozen=True)
+class PlacedRows:
+    """A point set's rows (float32 by default) as coordinate planes (d8,
+    chips x per_chip) split by columns over the mesh's points axis: n real
+    rows of d coordinates, zero columns after them (never gathered, masked
+    out of the cost), zero rows after d."""
+
+    planes: jax.Array
+    n: int
+    d: int
+    block: int
+    mesh: Any
+
+    def take(self, idx) -> jax.Array:
+        """(len(idx), d) rows at `idx`, bit for bit the placed rows."""
+        fn = _take_program(self.mesh, self.planes.shape, self.d)
+        return fn(self.planes, idx)
+
+    def cost(self, centers) -> jax.Array:
+        """The k-means cost of `centers` over the n real rows: per block
+        the distances of `plan._cost_program` (same arithmetic, default
+        matrix precision), the real rows' minima summed, one psum."""
+        fn = _rows_cost_program(self.mesh, self.planes.shape, self.n,
+                                self.d, self.block)
+        return fn(self.planes, centers)
+
+
+def place_rows(points: np.ndarray, mesh, *, dtype="float32") -> PlacedRows:
+    """`points` as `dtype` coordinate planes over `mesh`, each device's
+    columns cast and built on the host (side by side, for a set of more
+    than one prepare block) and copied to it alone."""
+    points = np.asarray(points)
+    n, d = points.shape
+    chips = _mesh_size(mesh, points_axis(mesh))
+    per_chip = -(-n // chips)
+    block = min(COST_BLOCK_ROWS, -(-per_chip // 128) * 128)
+    per_chip = -(-per_chip // block) * block
+    shape = (-(-d // 8) * 8, per_chip * chips)
+    sharding = NamedSharding(mesh, P(None, points_axis(mesh, shape[1])))
+
+    def columns(lo):
+        out = np.zeros((shape[0], per_chip), dtype)
+        rows = points[lo:min(lo + per_chip, n)]
+        out[:d, :len(rows)] = rows.T
+        return out
+
+    starts = range(0, shape[1], per_chip)
+    with host_block_map(len(starts) if n > PREPARE_BLOCK_ROWS else 1) as map_fn:
+        built = dict(zip(starts, map_fn(columns, starts)))
+    planes = jax.make_array_from_callback(
+        shape, sharding, lambda index: built[index[1].indices(shape[1])[0]])
+    return PlacedRows(planes, n=n, d=d, block=block, mesh=mesh)
+
+
+@functools.lru_cache(maxsize=None)
+def _take_program(mesh, shape, d):
+    axis = points_axis(mesh, shape[1])
+    n_loc = shape[1] // _mesh_size(mesh, axis)
+
+    def program(planes_loc, idx):
+        # Each device takes the rows it holds (mode="clip": in range for
+        # every index) and one psum assembles them: no device gathers
+        # another's columns.
+        loc = idx - jax.lax.axis_index(axis) * n_loc
+        mine = (loc >= 0) & (loc < n_loc)
+        rows = _plane_rows(planes_loc, loc, mode="clip")[:, :d]
+        return jax.lax.psum(jnp.where(mine[:, None], rows, 0), axis)
+
+    return jax.jit(jax.shard_map(
+        program, mesh=mesh, in_specs=(P(None, axis), P()), out_specs=P(),
+        check_vma=False))
+
+
+@functools.lru_cache(maxsize=None)
+def _rows_cost_program(mesh, shape, n_real, d, block):
+    axis = points_axis(mesh, shape[1])
+    n_loc = shape[1] // _mesh_size(mesh, axis)
+
+    def program(planes_loc, centers):
+        first = jax.lax.axis_index(axis) * n_loc
+
+        def body(b, total):
+            rows = jax.lax.dynamic_slice_in_dim(
+                planes_loc, b * block, block, axis=1)[:d].T
+            near = jnp.min(_pairwise_d2(rows, centers), axis=1)
+            live = first + b * block + jnp.arange(block) < n_real
+            return total + jnp.sum(jnp.where(live, near, 0.0))
+
+        local = jax.lax.fori_loop(0, n_loc // block, body, jnp.float32(0.0))
+        return jax.lax.psum(local, axis)
+
+    return jax.jit(jax.shard_map(
+        program, mesh=mesh, in_specs=(P(None, axis), P()), out_specs=P(),
+        check_vma=False))
+
+
+# ---------------------------------------------------------------------------
 # Host-facing wrappers, registered under "<name>/sharded".
 # ---------------------------------------------------------------------------
 
@@ -575,23 +855,18 @@ def sharded_rejection_seeder(points, k, rng, *, c=1.2, lsh_r=None,
     n = len(pts)
     mesh = mesh if mesh is not None else make_seeding_mesh()
     sched = resolve_schedule(schedule, batch)
-    data = prepare_rejection(
-        pts, seed=int(rng.integers(2 ** 31)), resolution=resolution,
-        lsh_r=lsh_r, num_tables=num_tables,
+    data = prepare_rejection_sharded(
+        pts, mesh=mesh, tile=tile, seed=int(rng.integers(2 ** 31)),
+        resolution=resolution, lsh_r=lsh_r, num_tables=num_tables,
         hashes_per_table=hashes_per_table,
     )
-    n_pad = _padded_for_mesh(n, mesh, tile)
-    lo = _pad_axis(data.codes_lo, 2, n_pad)
-    hi = _pad_axis(data.codes_hi, 2, n_pad)
-    pp = _pad_axis(data.points, 0, n_pad)
-    klo = _pad_axis(data.keys_lo, 1, n_pad)
-    khi = _pad_axis(data.keys_hi, 1, n_pad)
     t_prep = time.perf_counter() - t0
     bits = jax.random.key_data(jax.random.key(int(rng.integers(2 ** 31))))
     chosen, trials = sharded_rejection_sampling(
-        lo, hi, pp, klo, khi, k, bits, mesh=mesh,
-        scale=data.scale, num_levels=data.num_levels, m_init=data.m_init,
-        n_real=n, c=c, schedule=sched, max_rounds=max_rounds, tile=tile,
+        data.codes_lo, data.codes_hi, data.points, data.keys_lo,
+        data.keys_hi, k, bits, mesh=mesh, scale=data.scale,
+        num_levels=data.num_levels, m_init=data.m_init, n_real=n, c=c,
+        schedule=sched, max_rounds=max_rounds, tile=tile,
         interpret=interpret,
     )
     idx = np.asarray(jax.block_until_ready(chosen), dtype=np.int64)
@@ -671,8 +946,9 @@ SHARDED_SEEDERS = {
 # the plan's resolved execution context, so the padded artifacts — and the
 # lru-cached shard_map programs keyed on them — are reused across fits.
 #
-# The padded artifacts are `jax.device_put` onto the mesh with the exact
-# shardings the programs' `in_specs` expect (`_place` below), so the
+# The padded artifacts are placed on the mesh with the exact shardings the
+# programs' `in_specs` expect (`_place` below; the rejection seeder builds
+# and places them shard by shard, `prepare_rejection_sharded`), so the
 # cross-chip scatter happens once at prepare time and every solve starts
 # from correctly-placed buffers instead of re-laying them out per fit.
 # Donation is intentionally NOT applied to these buffers: they are the
@@ -715,31 +991,14 @@ def _solve_fastkmeanspp_sh(artifacts, pts, k, rng, *, c, schedule, options,
 
 
 def _prep_rejection_sh(pts, rng, *, resolution, options, execution):
-    data = prepare_rejection(
-        pts, seed=int(rng.integers(2 ** 31)), resolution=resolution,
+    data = prepare_rejection_sharded(
+        pts, mesh=execution.mesh, tile=execution.tile,
+        seed=int(rng.integers(2 ** 31)), resolution=resolution,
         lsh_r=options.get("lsh_r"),
         num_tables=options.get("num_tables", 15),
         hashes_per_table=options.get("hashes_per_table", 1),
     )
-    n_pad = _padded_for_mesh(len(pts), execution.mesh, execution.tile)
-    import dataclasses as _dc
-
-    mesh = execution.mesh
-    axis = points_axis(mesh, n_pad)
-    padded = _dc.replace(
-        data,
-        codes_lo=_place(_pad_axis(data.codes_lo, 2, n_pad), mesh,
-                        P(None, None, axis)),
-        codes_hi=_place(_pad_axis(data.codes_hi, 2, n_pad), mesh,
-                        P(None, None, axis)),
-        points=_place(_pad_axis(data.points, 0, n_pad), mesh,
-                      P(axis, None)),
-        keys_lo=_place(_pad_axis(data.keys_lo, 1, n_pad), mesh,
-                       P(None, axis)),
-        keys_hi=_place(_pad_axis(data.keys_hi, 1, n_pad), mesh,
-                       P(None, axis)),
-    )
-    return padded, len(pts)
+    return data, len(pts)
 
 
 def _solve_rejection_sh(artifacts, pts, k, rng, *, c, schedule, options,

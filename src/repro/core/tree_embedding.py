@@ -38,8 +38,12 @@ import jax.numpy as jnp
 __all__ = [
     "TreeEmbedding",
     "MultiTreeEmbedding",
+    "GridTrees",
     "build_multitree",
     "compute_max_dist",
+    "grid_trees",
+    "hash_level_planes",
+    "set_bounds",
     "sep_levels",
     "tree_dist_from_sep",
     "NUM_TREES",
@@ -48,15 +52,39 @@ __all__ = [
 NUM_TREES = 3  # the paper uses exactly three shifted trees ("multi-tree").
 
 
+BOUNDS_ROWS = 1 << 16   # rows per block of `set_bounds`
+
+
+def set_bounds(points: np.ndarray, *, map_fn=map) -> tuple[float, np.ndarray]:
+    """`compute_max_dist(points)` and the per-coordinate minimum, in one
+    pass over blocks of `BOUNDS_ROWS` rows.
+
+    Each block's temporaries are the block's size, never the set's, and
+    both results are exact whatever the blocks (a maximum of per-row
+    distances, a minimum of coordinates).  `map_fn` maps the per-block
+    work over the blocks' starts (a thread pool's `map` runs them side by
+    side).
+    """
+    x0 = points[0]
+
+    def block(lo):
+        rows = points[lo:lo + BOUNDS_ROWS]
+        far = np.sqrt(np.maximum(((rows - x0) ** 2).sum(axis=1), 0.0)).max()
+        return far, rows.min(axis=0)
+
+    parts = list(map_fn(block, range(0, len(points), BOUNDS_ROWS)))
+    d = max(far for far, _ in parts)
+    origin = np.min(np.stack([low for _, low in parts]), axis=0)
+    return (float(2.0 * d) if d > 0 else 1.0), origin
+
+
 def compute_max_dist(points: np.ndarray) -> float:
     """Upper bound on the diameter within a factor of 2 (paper §2, fn. 6).
 
     Picks the first point and doubles its maximum distance to any other point.
     O(nd).
     """
-    x0 = points[0]
-    d = np.sqrt(np.maximum(((points - x0) ** 2).sum(axis=1), 0.0)).max()
-    return float(2.0 * d) if d > 0 else 1.0
+    return set_bounds(np.asarray(points))[0]
 
 
 def _num_levels(max_dist: float, resolution: float) -> int:
@@ -111,6 +139,41 @@ class MultiTreeEmbedding:
         return np.stack([t.codes for t in self.trees])
 
 
+_MIX = np.uint64(0x9E3779B97F4A7C15)
+
+
+def _deep_cells(pts, origin, shift, max_dist, num_levels) -> np.ndarray:
+    """Cell coordinates at the deepest height, (..., d) float64 integers:
+    the only float work of the codes (a single floor-divide)."""
+    y = pts - origin
+    y += shift                  # all coords in [0, 2*max_dist)
+    y /= 2.0 * max_dist / (1 << (num_levels - 1))
+    return np.floor(y, out=y)
+
+
+def _hash_levels(cell_deep: np.ndarray, hash_mults: np.ndarray,
+                 num_levels: int) -> np.ndarray:
+    """(H, ...) uint64 codes of uint64 deepest-level cells (..., d).
+
+    Because level sides halve exactly, the level-h cell coordinate is the
+    deepest level's coordinate right-shifted by (H-1-h) bits, so every
+    height above is integer shifts + the per-level linear hash.
+    """
+    out = np.empty((num_levels,) + cell_deep.shape[:-1], dtype=np.uint64)
+    # Height 0 is the root: a single cell.
+    out[0] = 0
+    cell = np.empty_like(cell_deep)     # one buffer, reused at every height
+    with np.errstate(over="ignore"):
+        for h in range(1, num_levels):
+            np.right_shift(cell_deep, np.uint64(num_levels - 1 - h), out=cell)
+            cell *= hash_mults
+            code = cell.sum(axis=-1, dtype=np.uint64, out=out[h, ...])
+            # Mix in the height so identical cells at different heights differ.
+            code *= _MIX
+            code += np.uint64(h)
+    return out
+
+
 def _grid_codes(
     pts: np.ndarray,
     origin: np.ndarray,
@@ -119,28 +182,80 @@ def _grid_codes(
     num_levels: int,
     hash_mults: np.ndarray,
 ) -> np.ndarray:
-    """Hashed cell codes for every height; returns (H, ...) uint64.
+    """Hashed cell codes for every height; returns (H, ...) uint64."""
+    cells = _deep_cells(pts, origin, shift, max_dist, num_levels)
+    return _hash_levels(cells.astype(np.uint64), hash_mults, num_levels)
 
-    Because level sides halve exactly, the level-h cell coordinate is the
-    deepest level's coordinate right-shifted by (H-1-h) bits — so the float
-    work is a single floor-divide at the deepest level, and everything above
-    is integer shifts + the per-level linear hash.
-    """
-    y = (pts - origin) + shift  # all coords in [0, 2*max_dist)
-    root_side = 2.0 * max_dist
-    lead = pts.shape[:-1]
-    out = np.empty((num_levels,) + lead, dtype=np.uint64)
-    # Height 0 is the root: a single cell.
-    out[0] = 0
-    deep_side = root_side / (1 << (num_levels - 1))
-    cell_deep = np.floor(y / deep_side).astype(np.uint64)
-    with np.errstate(over="ignore"):
-        for h in range(1, num_levels):
-            cell = cell_deep >> np.uint64(num_levels - 1 - h)
-            code = (cell * hash_mults).sum(axis=-1, dtype=np.uint64)
-            # Mix in the height so identical cells at different heights differ.
-            out[h] = code * np.uint64(0x9E3779B97F4A7C15) + np.uint64(h)
-    return out
+
+def hash_level_planes(cells, hash_mults, rows, num_levels: int):
+    """The jnp twin of `_hash_levels`, traceable (call it with x64 on):
+    cells (..., b, d) of unsigned integers -> the codes of heights
+    1..H-1 (the root dropped) as (..., H-1, b) int32 lo/hi planes, bit
+    for bit `ops.split_codes_u64` of the NumPy codes, for the first
+    `rows` cells and zero after them.  The hash runs in uint64 (emulated
+    on a TPU)."""
+    c = cells.astype(jnp.uint64)
+    live = jnp.arange(cells.shape[-2]) < rows
+    lo, hi = [], []
+    for h in range(1, num_levels):
+        code = jnp.sum((c >> np.uint64(num_levels - 1 - h)) * hash_mults,
+                       axis=-1, dtype=jnp.uint64)
+        code = jnp.where(live, code * _MIX + np.uint64(h), np.uint64(0))
+        lo.append(jax.lax.bitcast_convert_type(
+            (code & np.uint64(0xFFFFFFFF)).astype(jnp.uint32), jnp.int32))
+        hi.append(jax.lax.bitcast_convert_type(
+            (code >> np.uint64(32)).astype(jnp.uint32), jnp.int32))
+    return jnp.stack(lo, axis=-2), jnp.stack(hi, axis=-2)
+
+
+@dataclasses.dataclass(frozen=True)
+class GridTrees:
+    """The random draws of `num_trees` grid embeddings over one point
+    set's bounds: codes for any block of rows, each block on its own and
+    bit for bit as the whole set's codes."""
+
+    max_dist: float
+    num_levels: int
+    origin: np.ndarray           # (d,) per-coordinate min of the set
+    shifts: tuple                # per tree, (d,) float64
+    mults: tuple                 # per tree, (d,) odd uint64
+
+    def tree_codes(self, rows: np.ndarray, tree: int) -> np.ndarray:
+        """(H, b) uint64 codes of `rows` (float64) in tree `tree`."""
+        return _grid_codes(rows, self.origin, self.shifts[tree],
+                           self.max_dist, self.num_levels, self.mults[tree])
+
+    @property
+    def cell_dtype(self) -> np.dtype:
+        """The narrowest unsigned dtype that holds a deepest-level cell
+        coordinate (they lie in [0, 2^(H-1)))."""
+        return np.min_scalar_type((1 << (self.num_levels - 1)) - 1)
+
+    def cells(self, rows: np.ndarray, tree: int) -> np.ndarray:
+        """(b, d) deepest-level cells of `rows` in tree `tree`, as
+        `cell_dtype`: what `hash_level_planes` hashes into codes."""
+        return _deep_cells(rows, self.origin, self.shifts[tree],
+                           self.max_dist, self.num_levels).astype(
+                               self.cell_dtype)
+
+
+def grid_trees(max_dist: float, origin: np.ndarray, *, seed: int = 0,
+               resolution: Optional[float] = None,
+               num_trees: int = NUM_TREES) -> GridTrees:
+    """The shifts and hash multipliers of MULTITREEINIT, drawn from `seed`
+    (the draws `build_multitree` makes, in its order)."""
+    rng = np.random.default_rng(seed)
+    d = len(origin)
+    if resolution is None:
+        resolution = max_dist * 1e-6
+    shifts, mults = [], []
+    for _ in range(num_trees):
+        shifts.append(rng.uniform(0.0, max_dist, size=d))
+        mults.append(rng.integers(1, 2 ** 63, size=d, dtype=np.uint64)
+                     * np.uint64(2) + np.uint64(1))
+    return GridTrees(max_dist=max_dist,
+                     num_levels=_num_levels(max_dist, resolution),
+                     origin=origin, shifts=tuple(shifts), mults=tuple(mults))
 
 
 def build_multitree(
@@ -167,32 +282,27 @@ def build_multitree(
     """
     pts = np.asarray(points, dtype=np.float64)
     n, d = pts.shape
-    rng = np.random.default_rng(seed)
-    max_dist = compute_max_dist(pts) if max_dist is None else float(max_dist)
-    if resolution is None:
-        resolution = max_dist * 1e-6
-    levels = _num_levels(max_dist, resolution)
-    origin = pts.min(axis=0)
-    trees = []
-    for _ in range(num_trees):
-        shift = rng.uniform(0.0, max_dist, size=d)
-        mults = rng.integers(1, 2 ** 63, size=d, dtype=np.uint64) * np.uint64(2) + np.uint64(1)
-        codes = _grid_codes(pts, origin, shift, max_dist, levels, mults)
-        trees.append(
-            TreeEmbedding(
-                codes=codes,
-                max_dist=max_dist,
-                num_levels=levels,
-                dim=d,
-                shift=shift,
-                origin=origin,
-                hash_mults=mults,
-            )
+    if max_dist is None:
+        max_dist, origin = set_bounds(pts)
+    else:
+        max_dist, origin = float(max_dist), pts.min(axis=0)
+    grid = grid_trees(max_dist, origin, seed=seed, resolution=resolution,
+                      num_trees=num_trees)
+    trees = tuple(
+        TreeEmbedding(
+            codes=grid.tree_codes(pts, t),
+            max_dist=max_dist,
+            num_levels=grid.num_levels,
+            dim=d,
+            shift=grid.shifts[t],
+            origin=origin,
+            hash_mults=grid.mults[t],
         )
+        for t in range(num_trees))
     return MultiTreeEmbedding(
-        trees=tuple(trees),
+        trees=trees,
         max_dist=max_dist,
-        num_levels=levels,
+        num_levels=grid.num_levels,
         dim=d,
         num_points=n,
     )

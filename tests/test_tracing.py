@@ -119,6 +119,28 @@ def _mixture(n, d=4, k_true=5, seed=0):
     return ctr[rng.integers(k_true, size=n)] + rng.normal(size=(n, d))
 
 
+def _settled(client, before: dict, timeout: float = 30.0) -> dict:
+    """The server's stats over the wire once every delivery has closed.
+
+    A client holds its answer while the solve worker may still be inside
+    the delivery that sent it: the span `repro.engine.callbacks` and the
+    server's `results_sent` count it only after the frame went out.  So
+    the stats are read again until both cover the four answers and every
+    lane, or as they stand when `timeout` runs out."""
+    deadline = time.monotonic() + timeout
+    while True:
+        after = client.stats()
+        lanes = after["lanes"] - before["lanes"]
+        sent = after["net"]["results_sent"] - before["net"]["results_sent"]
+        delivered = _gained(before["engine"]["spans"],
+                            after["engine"]["spans"],
+                            "repro.engine.callbacks")[1]
+        if (sent >= 4 and delivered >= lanes) or \
+                time.monotonic() > deadline:
+            return after
+        time.sleep(0.05)
+
+
 def test_served_path_counts_one_span_per_boundary():
     """Two closed-loop clients through a `ClusterServer` whose frontend
     forms lanes of up to two, on the device program."""
@@ -140,7 +162,7 @@ def test_served_path_counts_one_span_per_boundary():
         for t in pool:
             t.join(timeout=400)
         assert not any(t.is_alive() for t in pool)
-        after = clients[0].stats()              # over the wire
+        after = _settled(clients[0], before)    # over the wire
         for c in clients:
             c.close()
     assert "solve_seconds" not in after["engine"]
